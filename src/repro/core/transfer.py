@@ -41,11 +41,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.transport import Transport
 
-try:  # jax >= 0.4.44 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 class TransferMode(enum.Enum):
     DIRECT_HBM = "direct_hbm"  # GDR
@@ -92,7 +87,7 @@ def _permute_leaf(x, mesh, perm):
         return jax.lax.ppermute(x_l, "pod", perm)
 
     spec = P(*(("pod",) + (None,) * (x.ndim - 1)))
-    return _shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
 
 
 def kv_transfer(caches, mesh, *, mode: TransferMode = TransferMode.DIRECT_HBM,

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Each op accepts model-layout tensors, handles padding/transposes, and picks
-interpret mode automatically on CPU (the kernels TARGET TPU; interpret=True
-executes the kernel body in Python for validation — see DESIGN.md §6).
+Each op accepts model-layout tensors and handles padding/transposes. The
+kernels target the TPU and compile with Mosaic there; on the CPU backend
+(the tests) they run in interpret mode, which executes the kernel body for
+validation. Any other backend raises instead of interpreting in silence.
 """
 
 from __future__ import annotations
@@ -20,7 +21,15 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for 'tpu' and interpret on 'cpu'; the "
+        f"{backend!r} backend is neither"
+    )
 
 
 def _pad_to(x, axis, mult):

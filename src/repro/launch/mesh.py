@@ -25,11 +25,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.5 explicit-sharding API; older jax has no AxisType
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12  # FLOP/s
@@ -38,9 +34,26 @@ ICI_BW = 50e9  # B/s per link
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def _serving_devices(need: int, what: str) -> list:
+    """The first ``need`` devices, or fewer on the CPU backend only.
+
+    Tests run the multi-pod tiers on one CPU device, where the pod axis
+    clamps to what exists and stages share it. On an accelerator that
+    clamp would stack stages or replicas onto one chip without a word,
+    so asking for more chips than there are raises instead.
+    """
+    avail = jax.devices()
+    if need <= len(avail):
+        return avail[:need]
+    if jax.default_backend() == "cpu":
+        return avail
+    raise ValueError(
+        f"{what} needs {need} {avail[0].platform} devices; "
+        f"{len(avail)} present"
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -57,18 +70,23 @@ def make_local_mesh():
 def make_serving_pod_mesh(npods=None):
     """('pod',)-axis serving mesh over the first ``npods`` devices.
 
-    Defaults to 2 pods when the backend has at least two devices, else the
-    1-pod degenerate mesh (every collective becomes an identity permute,
-    so the full disaggregated tier still runs in single-device tests).
-    Re-exported as ``repro.serving.make_pod_mesh``.
+    Defaults to 2 pods. On the CPU backend the default clamps to the
+    devices present, down to the 1-pod degenerate mesh (every collective
+    becomes an identity permute, so the full disaggregated tier still runs
+    in single-device tests); on an accelerator it raises when fewer than
+    two chips exist. An explicit ``npods`` beyond the devices present
+    raises on every backend. Re-exported as ``repro.serving.make_pod_mesh``.
     """
     from jax.sharding import Mesh
 
-    avail = jax.devices()
-    npods = min(2, len(avail)) if npods is None else npods
-    if npods > len(avail):
-        raise ValueError(f"npods {npods} > available devices {len(avail)}")
-    return Mesh(np.asarray(avail[:npods]), ("pod",))
+    if npods is None:
+        devs = _serving_devices(2, "the default 2-pod serving mesh")
+    else:
+        avail = jax.devices()
+        if npods > len(avail):
+            raise ValueError(f"npods {npods} > available devices {len(avail)}")
+        devs = avail[:npods]
+    return Mesh(np.asarray(devs), ("pod",))
 
 
 def make_cluster_mesh(n_replicas: int, pods_per_replica: int = 1):
@@ -76,12 +94,13 @@ def make_cluster_mesh(n_replicas: int, pods_per_replica: int = 1):
 
     The pod axis spans ``n_replicas * pods_per_replica`` slots —
     ``pods_per_replica`` is 1 for fused-engine replicas and 2 for
-    disaggregated (prefill pod + decode pod) replicas. When the backend
-    has fewer devices than slots, the axis clamps to what exists and the
-    cluster's replica slices overlap modulo the axis (the degenerate
-    single-device case runs every replica on one CPU, which is what lets
-    the full cluster tier execute in tier-1 tests); with enough devices
-    every replica owns a disjoint slice.
+    disaggregated (prefill pod + decode pod) replicas. When the CPU
+    backend has fewer devices than slots, the axis clamps to what exists
+    and the cluster's replica slices overlap modulo the axis (the
+    degenerate single-device case runs every replica on one CPU, which is
+    what lets the full cluster tier execute in tier-1 tests); on an
+    accelerator too few chips raise. With enough devices every replica
+    owns a disjoint slice.
     """
     from jax.sharding import Mesh
 
@@ -90,6 +109,8 @@ def make_cluster_mesh(n_replicas: int, pods_per_replica: int = 1):
             f"need n_replicas >= 1 and pods_per_replica >= 1: "
             f"({n_replicas}, {pods_per_replica})"
         )
-    avail = jax.devices()
-    need = n_replicas * pods_per_replica
-    return Mesh(np.asarray(avail[:min(need, len(avail))]), ("pod",))
+    devs = _serving_devices(
+        n_replicas * pods_per_replica,
+        f"a {n_replicas}-replica cluster of {pods_per_replica} pod(s) each",
+    )
+    return Mesh(np.asarray(devs), ("pod",))

@@ -11,19 +11,30 @@ from repro.serving.request import Request
 
 
 class ClosedLoopClient:
+    """One closed-loop client. Prompts are ``prompt_len`` tokens long, or,
+    with ``prompt_len_max``, drawn uniformly from
+    ``[prompt_len, prompt_len_max]``. Every request made is kept in
+    ``sent``; the engine fills each one's ``generated`` tokens."""
+
     def __init__(self, client_id: int, vocab: int, *, prompt_len: int = 32,
-                 max_new_tokens: int = 8, priority: int = 0, seed: int = 0):
+                 max_new_tokens: int = 8, priority: int = 0, seed: int = 0,
+                 prompt_len_max: int = 0):
         self.client_id = client_id
         self.vocab = vocab
         self.prompt_len = prompt_len
+        self.prompt_len_max = max(prompt_len_max, prompt_len)
         self.max_new_tokens = max_new_tokens
         self.priority = priority
         self.rng = np.random.default_rng(seed + client_id)
         self.inflight = None
         self.completed = []
+        self.sent = []
 
     def make_request(self) -> Request:
-        toks = self.rng.integers(0, self.vocab, self.prompt_len, dtype=np.int32)
+        n = self.prompt_len
+        if self.prompt_len_max > n:
+            n = int(self.rng.integers(n, self.prompt_len_max + 1))
+        toks = self.rng.integers(0, self.vocab, n, dtype=np.int32)
         req = Request(
             prompt_tokens=toks,
             max_new_tokens=self.max_new_tokens,
@@ -31,6 +42,7 @@ class ClosedLoopClient:
             client_id=self.client_id,
         )
         self.inflight = req.request_id
+        self.sent.append(req)
         return req
 
     def complete(self, response):

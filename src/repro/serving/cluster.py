@@ -610,8 +610,10 @@ class ServingCluster:
         :meth:`step`) or 'process' (each replica is its OWN OS process
         with its own XLA client over ``devices_per_replica`` forced host
         devices, spoken to over the socket RPC control plane — real
-        concurrency, the deployment shape the paper measures). The
-        process backend rebuilds each worker's params deterministically
+        concurrency, the deployment shape the paper measures). Workers
+        are pinned to the CPU, so the process backend refuses to start
+        when this process holds an accelerator. The process backend
+        rebuilds each worker's params deterministically
         from ``model.init(jax.random.key(param_seed))``; pass params
         built from the SAME seed for in-process-vs-process A/B identity.
         Worker startup is overlapped across replicas; ``with`` the
@@ -690,10 +692,19 @@ class ServingCluster:
         its own XLA client over its forced host-device subset), overlap
         their init (jax import + deterministic param rebuild + optional
         warmup), and wrap each in a :class:`ProcessReplica`."""
+        import jax
         import numpy as np
 
         from repro.serving.ipc import ReplicaClient
 
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"backend='process' runs each replica on forced host CPU "
+                f"devices in its own process, but this process already "
+                f"holds the {backend!r} backend, and a chip belongs to one "
+                f"process; use backend='inprocess' to serve on the chip"
+            )
         devices = (1 if engine == "fused" else 2) \
             if devices_per_replica is None else int(devices_per_replica)
         spec = {

@@ -112,9 +112,9 @@ WARM_PRETRACE_TABLE = frozenset({
 
 
 def make_pod_mesh(npods: Optional[int] = None):
-    """('pod',)-axis mesh over the first ``npods`` devices (default 2 when
-    the backend has them, else the 1-pod degenerate mesh). Thin re-export
-    of ``launch.mesh.make_serving_pod_mesh``."""
+    """('pod',)-axis mesh over the first ``npods`` devices (default 2; the
+    CPU backend clamps to the 1-pod degenerate mesh). Thin re-export of
+    ``launch.mesh.make_serving_pod_mesh``."""
     from repro.launch.mesh import make_serving_pod_mesh
 
     return make_serving_pod_mesh(npods)

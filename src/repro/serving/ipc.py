@@ -233,7 +233,10 @@ class ReplicaClient:
 
         env = os.environ.copy()
         # the child's OWN XLA client over its own forced host-device
-        # subset; any parent-side forcing must not leak through
+        # subset; any parent-side forcing must not leak through. Pinned to
+        # the CPU: an accelerator belongs to one process, and a worker
+        # that found it held would otherwise fall back to the CPU unseen
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={self.devices}"
         )

@@ -47,8 +47,11 @@ def _build_pipeline(spec: dict):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models.model import Model
     from repro.serving.engine import EnginePipeline, ServingEngine
+
+    use_compile_cache()
 
     model = Model(spec["cfg"], dtype=getattr(jnp, spec.get("dtype", "float32")))
     # weights from the seed, not the wire: deterministic reconstruction is

@@ -1,5 +1,6 @@
-# NOTE: no XLA_FLAGS here on purpose — smoke tests and benches must see the
-# real single CPU device; only launch/dryrun.py forces 512 host devices.
+# NOTE: no XLA_FLAGS here on purpose — the tests see the single CPU device
+# (run them with JAX_PLATFORMS=cpu); tests that need more devices start a
+# subprocess with forced host devices.
 import dataclasses
 
 import numpy as np

@@ -210,6 +210,33 @@ def test_replica_pod_slices():
     assert replica_pod_slices(1, 2, 2) == [(0,), (0,)]
 
 
+@pytest.mark.parametrize("backend,make,raises", [
+    ("cpu", "cluster", False),
+    ("cpu", "pod", False),
+    ("tpu", "cluster", True),
+    ("tpu", "pod", True),
+])
+def test_meshes_clamp_only_on_cpu(monkeypatch, backend, make, raises):
+    """On the CPU backend a mesh asking for more devices than exist clamps
+    (the single-device tests run every tier); on a chip it raises rather
+    than stack stages or replicas onto one chip in silence."""
+    from repro.launch.mesh import make_cluster_mesh, make_serving_pod_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = len(jax.devices())
+    build = {
+        "cluster": lambda: make_cluster_mesh(n + 1, 2),
+        "pod": lambda: make_serving_pod_mesh(),
+    }[make]
+    if n >= 2 and make == "pod":
+        pytest.skip("the default 2-pod mesh fits this backend")
+    if raises:
+        with pytest.raises(ValueError, match="present"):
+            build()
+    else:
+        assert build().shape["pod"] == n
+
+
 def test_least_loaded_beats_round_robin_on_skewed_trace(model_bank):
     """Deterministic skew: one long-budget request, then a burst of
     1-token requests. Round-robin blindly parks half the lights behind

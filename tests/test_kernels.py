@@ -112,3 +112,40 @@ def test_preprocess_sweep(rng, N, D):
     np.testing.assert_allclose(
         out.astype(jnp.float32), want.astype(jnp.float32), atol=1e-2, rtol=1e-2
     )
+
+
+# --------------------------------------------------------------------------- #
+def _odd_op_calls(rng):
+    """One call per public op, at shapes no other test traces (a cached
+    trace would skip the backend check)."""
+    q = arr(rng, 1, 3, 2, 8)
+    kv = arr(rng, 1, 3, 1, 8)
+    lens = jnp.asarray([3], jnp.int32)
+    return {
+        "flash_attention": lambda: ops.flash_attention(q, kv, kv),
+        "decode_attention": lambda: ops.decode_attention(
+            arr(rng, 1, 1, 2, 8), kv, kv, lens),
+        "paged_decode_attention": lambda: ops.paged_decode_attention(
+            arr(rng, 1, 1, 2, 8), arr(rng, 2, 3, 1, 8), arr(rng, 2, 3, 1, 8),
+            jnp.asarray([[1]], jnp.int32), lens),
+        "ssd_scan": lambda: ops.ssd_scan(
+            arr(rng, 1, 3, 1, 8), arr(rng, 1, 3, 1), arr(rng, 1),
+            arr(rng, 1, 3, 1, 4), arr(rng, 1, 3, 1, 4)),
+        "rmsnorm": lambda: ops.rmsnorm(arr(rng, 3, 24), arr(rng, 24)),
+        "preprocess": lambda: ops.preprocess(
+            jnp.zeros((3, 24), jnp.uint8), arr(rng, 24), arr(rng, 24)),
+    }
+
+
+@pytest.mark.parametrize(
+    "name",
+    ["flash_attention", "decode_attention", "paged_decode_attention",
+     "ssd_scan", "rmsnorm", "preprocess"],
+)
+def test_ops_refuse_backend_without_kernels(rng, monkeypatch, name):
+    """Interpret mode is for the CPU backend only: on a backend that is
+    neither 'cpu' nor 'tpu' the ops raise instead of interpreting."""
+    call = _odd_op_calls(rng)[name]
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' backend"):
+        call()

@@ -156,3 +156,22 @@ def test_process_cluster_policy_sweep_drains():
         tel = pc.telemetry()
         assert sum(r["emitted"] for r in tel["ipc"]) == 24
         assert all(r["submitted"] == r["emitted"] for r in tel["ipc"])
+
+
+def test_process_backend_refuses_accelerator_parent(monkeypatch):
+    """A parent holding a chip must not spawn workers that would fall back
+    to the CPU unseen: backend='process' raises before spawning any."""
+    import jax
+
+    from repro.models import Model
+    from repro.serving import ServingCluster
+    from repro.serving import ipc
+
+    spawned = []
+    monkeypatch.setattr(ipc, "ReplicaClient",
+                        lambda *a, **k: spawned.append(1))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the 'tpu' backend"):
+        ServingCluster.build(Model(micro_config()), None, n_replicas=2,
+                             backend="process", **KW)
+    assert not spawned
