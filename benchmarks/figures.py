@@ -319,7 +319,9 @@ def fig_stage_breakdown():
     of the paper's stage-breakdown figures (Figs. 6/8), rendered from the
     span walls exported by the traced drains in benchmarks/disagg.py
     (``stage_walls_s`` in ``BENCH_disagg.json``). The ``request`` root span
-    covers its children and the ``submit`` span is instant, so both are
+    covers its children, ``engine.step`` and ``engine.admit`` contain the
+    phases stacked beside them, ``engine.prefill`` repeats the per-request
+    ``prefill.*`` walls and the ``submit`` span is instant, so all are
     excluded from the stack."""
     import json
     from pathlib import Path
@@ -337,17 +339,18 @@ def fig_stage_breakdown():
 
     stacked = {
         m: {k: v for k, v in walls[m].items()
-            if k not in ("request", "submit") and v > 0}
+            if k not in ("request", "submit", "engine.step", "engine.admit",
+                         "engine.prefill") and v > 0}
         for m in mechs
     }
     claims = [
         ("fig-stage: every mechanism exports traced stage walls",
          all(walls[m] for m in mechs)),
-        ("fig-stage: every mechanism has a transfer span wall",
-         all(walls[m].get("transfer", 0.0) > 0 for m in mechs)),
+        ("fig-stage: every mechanism has a handoff span wall",
+         all(walls[m].get("engine.handoff", 0.0) > 0 for m in mechs)),
         ("fig-stage: every mechanism has prefill + decode span walls",
          all(any(k.startswith("prefill.") for k in walls[m])
-             and "decode.window" in walls[m] for m in mechs)),
+             and "engine.harvest" in walls[m] for m in mechs)),
         ("fig-stage: stage vocabularies agree across mechanisms",
          len({frozenset(stacked[m]) for m in mechs}) == 1),
     ]

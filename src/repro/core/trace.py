@@ -4,8 +4,8 @@ The profiler (:mod:`repro.core.profiler`) charges stage *durations* to
 ``RequestRecord.stage_s`` — enough for the paper's Table-I breakdown
 means, but blind to *when* each stage ran. This module adds the missing
 axis: every pipeline hop (gateway submit/response, router decision,
-queue wait, prefill dispatch, KV handoff, decode windows, pipeline
-threads, IPC RPC frames) emits a :class:`Span` with ``perf_counter``
+queue wait, engine step phases, KV handoff, pipeline threads, IPC RPC
+frames) emits a :class:`Span` with ``perf_counter``
 start/end stamps into a process-global ring buffer, and the whole
 multi-process timeline exports as Chrome trace-event JSON (loadable at
 https://ui.perfetto.dev) or a text stage summary.
@@ -24,6 +24,12 @@ Design constraints, in order:
   the parent clock by subtracting the socket-handshake
   ``clock_offset`` — the same machinery
   :func:`repro.core.metrics.merge_record_streams` uses for records.
+* **On the profiler's clock.** :class:`span` opens a
+  ``jax.profiler.TraceAnnotation`` under the same name and attributes
+  whether or not the ring is on, so a profiled run holds the engine's
+  host phases in the same timeline as the device's programs (what
+  ``bench/engine_spans.py`` reads). With no profiler session a span
+  costs 1.4–2.0 µs on a TPU v5e host.
 * **Self-verifying.** :meth:`Trace.reconcile` checks every request's
   span tree against its charged ``stage_s`` (root span present, span
   walls cover each charge within epsilon) and
@@ -37,6 +43,7 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
@@ -44,7 +51,7 @@ from typing import Optional
 from repro.core.metrics import mean, percentile
 
 __all__ = [
-    "Span", "TraceBuffer", "Trace", "tracer", "enable_tracing",
+    "Span", "TraceBuffer", "Trace", "span", "tracer", "enable_tracing",
     "disable_tracing", "tracing_enabled", "spans_to_wire",
     "spans_from_wire", "validate_stamps",
 ]
@@ -58,8 +65,8 @@ class Span:
 
     ``t_start``/``t_end`` are ``time.perf_counter`` stamps in the clock
     of the process named by ``process`` (after rebasing: the parent
-    clock). ``request_id`` is None for process-level spans (decode
-    windows, RPC frames, router decisions); request-scoped spans carry
+    clock). ``request_id`` is None for process-level spans (engine
+    phases, RPC frames, router decisions); request-scoped spans carry
     the id so :meth:`Trace.by_request` can build per-request trees.
     ``attrs`` holds primitive metadata only (mechanism, wire bytes,
     modeled-vs-measured charge provenance, ...).
@@ -232,6 +239,51 @@ def tracing_enabled() -> bool:
     return _GLOBAL.enabled
 
 
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+class span:
+    """One host phase on both sinks: ``with span("engine.prefill",
+    path="bucket", rows=2): ...``.
+
+    Always opens a ``jax.profiler.TraceAnnotation(name, **attrs)``, so
+    the phase lands in a profiler session's host plane with the attrs as
+    event stats; when the global ring is enabled it also records the
+    same span there with ``perf_counter`` stamps (request-less, on the
+    calling thread). :meth:`set` adds attrs known only once the phase has
+    run (e.g. how many requests an admission placed)."""
+
+    __slots__ = ("name", "attrs", "_ann", "_t0")
+
+    def __init__(self, name: str, **attrs):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self.name = name
+        self.attrs = attrs
+        self._ann = _annotation(name, **attrs)
+        self._t0 = None
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        if _GLOBAL.enabled:
+            self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        if self._t0 is not None:
+            _GLOBAL.emit(self.name, self._t0, time.perf_counter(),
+                         **self.attrs)
+        return False
+
+
 def validate_stamps(t_arrival: float, t_first_token: float, t_done: float,
                     *, where: str = "", tol: float = 1e-9):
     """Debug-mode monotonicity check for the engine-filled Request
@@ -360,9 +412,12 @@ class Trace:
         * Per request: exactly one root ``request`` span, every other
           span of that request inside the root interval (± eps).
         * Per (process, thread, engine tag): process-level spans
-          (request_id None) must not overlap — each thread's timeline is
-          sequential, so overlap means a clock went backwards or an
-          interval was mis-stamped. Request-scoped spans are exempt: a
+          (request_id None) either nest or are disjoint — each thread's
+          timeline is one call stack, so a span that starts inside
+          another and ends after it means a clock went backwards or an
+          interval was mis-stamped — and no span nests inside one of its
+          own name (no engine phase re-enters itself, so that too is a
+          mis-stamp). Request-scoped spans are exempt: a
           batched admission legitimately gives co-admitted requests
           identical prefill intervals.
         """
@@ -393,13 +448,23 @@ class Trace:
             key = (s.process, s.thread, s.attrs.get("tag", ""))
             lanes.setdefault(key, []).append(s)
         for key, spans in lanes.items():
-            spans.sort(key=lambda s: (s.t_start, s.t_end))
-            for a, b in zip(spans, spans[1:]):
-                if b.t_start < a.t_end - eps:
+            spans.sort(key=lambda s: (s.t_start, -s.t_end))
+            stack: list = []  # the enclosing spans, outermost first
+            for b in spans:
+                while stack and stack[-1].t_end <= b.t_start + eps:
+                    stack.pop()
+                if stack and b.t_end > stack[-1].t_end + eps:
+                    a = stack[-1]
                     problems.append(
                         f"lane {key}: '{b.name}' starts {a.t_end - b.t_start:.6f}s "
                         f"before '{a.name}' ends"
                     )
+                elif any(a.name == b.name for a in stack):
+                    problems.append(
+                        f"lane {key}: '{b.name}' at {b.t_start:.6f} nests "
+                        f"inside another '{b.name}'"
+                    )
+                stack.append(b)
         return problems
 
     def reconcile(self, records, eps: float = 2e-3) -> list:

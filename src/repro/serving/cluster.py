@@ -192,12 +192,6 @@ class Replica:
     def metrics_snapshot(self) -> dict:
         return self.engine.metrics_snapshot()
 
-    def trace_flush(self) -> None:
-        """Close the engine's open decode window (drain-end hook)."""
-        tf = getattr(self.engine, "trace_flush", None)
-        if callable(tf):
-            tf()
-
     def drain(self, deadline_s: float = 120.0) -> list:
         """Step to idle (bounded); returns the finished responses."""
         out = []
@@ -210,7 +204,6 @@ class Replica:
                     f"replica {self.index} drain exceeded {deadline_s}s"
                 )
         out.extend(self.step())
-        self.trace_flush()
         return out
 
     def close(self) -> None:
@@ -417,9 +410,6 @@ class ProcessReplica:
 
     def metrics_snapshot(self) -> dict:
         return self.client.telemetry().get("metrics", {})
-
-    def trace_flush(self) -> None:
-        pass  # the worker flushes its own windows at drain
 
     def close(self) -> None:
         self.client.close()
@@ -658,8 +648,8 @@ class ServingCluster:
         replicas = []
         for i, pods in enumerate(slices):
             # per-replica trace tag: in-process replicas share MainThread,
-            # so the tag is what keeps their process-level spans (decode
-            # windows, handoffs) on distinct trace lanes
+            # so the tag is what keeps their process-level spans (engine
+            # phases, handoffs) on distinct trace lanes
             kw_i = dict(engine_kw)
             kw_i.setdefault("trace_tag", f"replica{i}")
             if engine == "fused":
@@ -788,8 +778,6 @@ class ServingCluster:
         for _ in range(max_steps):
             out.extend(self.step())
             if self.idle:
-                for rep in self.replicas:
-                    rep.trace_flush()
                 break
         return out
 

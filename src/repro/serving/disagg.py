@@ -739,6 +739,16 @@ class DisaggregatedEngine(ServingEngine):
             )
 
     # ------------------------------------------------------------------ #
+    def _trace_handoff(self, wire_bytes: int, requests: int,
+                       measured: bool) -> trace.span:
+        """``engine.handoff``: the collective plus its landing, the wall
+        the 'transfer' stage charges."""
+        return self._trace_span(
+            "engine.handoff", mechanism=self.transfer_mode.name,
+            wire_bytes=wire_bytes, requests=requests,
+            charge="measured" if measured else "modeled",
+        )
+
     def _handoff_paged(self, art: PrefillArtifact):  # reprolint: disable=RL001 the block IS the measurement: 'transfer' wall must cover wire completion
         """Paged pod-boundary handoff: move the bucket-width SUFFIX cache
         only. Reused prefix KV already lives in decode-pool blocks (it
@@ -761,22 +771,17 @@ class DisaggregatedEngine(ServingEngine):
             warm_s = time.perf_counter() - tw
         tiled = prep(payload)
         jax.block_until_ready(tiled)
-        t0 = time.perf_counter()
-        landed = move(tiled)
-        jax.block_until_ready(landed)
-        wall = time.perf_counter() - t0
-
         wire_now = payload_wire_bytes(payload, self.transfer_mode)
+        with self._trace_handoff(wire_now, len(art.reqs), measured):
+            t0 = time.perf_counter()
+            landed = move(tiled)
+            jax.block_until_ready(landed)
+            wall = time.perf_counter() - t0
+
         self.handoffs += 1
         self.handoff_wall_s += wall
         self.handoff_wire_bytes += wire_now
         self.handoff_payload_bytes += self._paged_geometry_bytes(n, L)
-        trace.tracer().emit(
-            "transfer", t0, t0 + wall, tag=self.trace_tag,
-            mechanism=self.transfer_mode.name, wire_bytes=wire_now,
-            requests=len(art.reqs),
-            charge="measured" if measured else "modeled",
-        )
         share = wall / max(len(art.reqs), 1)
         # per-request useful bytes = each row's UNCACHED suffix (its reused
         # prefix rode an earlier handoff; charging it again would double-
@@ -851,21 +856,16 @@ class DisaggregatedEngine(ServingEngine):
         # backends — covers exactly the collective + decode-slice landing
         tiled = prep(payload)
         jax.block_until_ready(tiled)
-        t0 = time.perf_counter()
-        landed = move(tiled)
-        jax.block_until_ready(landed)
-        wall = time.perf_counter() - t0
-
         wire_now = payload_wire_bytes(payload, self.transfer_mode)
+        with self._trace_handoff(wire_now, len(art.reqs), measured):
+            t0 = time.perf_counter()
+            landed = move(tiled)
+            jax.block_until_ready(landed)
+            wall = time.perf_counter() - t0
+
         self.handoffs += 1
         self.handoff_wall_s += wall
         self.handoff_wire_bytes += wire_now
-        trace.tracer().emit(
-            "transfer", t0, t0 + wall, tag=self.trace_tag,
-            mechanism=self.transfer_mode.name, wire_bytes=wire_now,
-            requests=len(art.reqs),
-            charge="measured" if measured else "modeled",
-        )
         share = wall / max(len(art.reqs), 1)
         # per-request TRUE cache lengths ride the (already materialized)
         # landed metadata — for feature-carrying requests the cache extends

@@ -78,6 +78,7 @@ step runs the whole active batch through one jitted step.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue as queue_mod
 import threading
@@ -712,16 +713,12 @@ class ServingEngine:
         self.decode_steps = 0  # total whole-batch decode dispatches
         self.useful_steps = 0  # harvested steps that advanced a live request
         # tracing (core/trace): request-scoped queue/prefill spans, a root
-        # span per finished request, and WINDOWED decode spans (one span
-        # per _TRACE_WINDOW_STEPS harvested steps, not per-step spam).
-        # trace_tag names this engine's process-level span lane so
-        # co-resident engines (in-process cluster replicas) don't share a
-        # sequential-timeline check lane.
+        # span per finished request, and engine.* phase spans (step,
+        # admit, prefill, dispatch, harvest) that also land on the
+        # profiler's clock. trace_tag names this engine's process-level
+        # span lane so co-resident engines (in-process cluster replicas)
+        # don't share a timeline check lane.
         self.trace_tag = trace_tag
-        self._win_t0: Optional[float] = None
-        self._win_end = 0.0
-        self._win_steps = 0
-        self._win_busy = 0
         # debug-mode stamp validation: every finished request's
         # t_arrival/t_first_token/t_done monotonicity is checked (a stage
         # clock running backwards here means a bad cross-process rebase)
@@ -1163,9 +1160,18 @@ class ServingEngine:
         return 0.0
 
     # ------------------------------------------------------------------ #
-    # Tracing emitters (core/trace) — all no-ops unless tracing is on
+    # Tracing emitters (core/trace)
     # ------------------------------------------------------------------ #
-    _TRACE_WINDOW_STEPS = 8  # harvested decode steps per window span
+    def _trace_span(self, name: str, **attrs) -> trace.span:
+        """An ``engine.*`` phase span on this engine's lane."""
+        return trace.span(name, tag=self.trace_tag, **attrs)
+
+    def _trace_prefill(self, path: str, width: int, rows: int) -> trace.span:
+        """``engine.prefill`` over one prefill call, dispatch through its
+        host fence; ``live_rows`` counts the decode rows it holds up."""
+        live = sum(1 for r in self.pool.slots if r is not None)
+        return self._trace_span("engine.prefill", path=path, bucket=width,
+                                rows=rows, live_rows=live)
 
     def _trace_admission(self, path: str, reqs: list, t0: float, now: float,
                          dt: float, n: int, **attrs):
@@ -1181,45 +1187,6 @@ class ServingEngine:
             tr.emit("queue", rec.t_issue, t0, request_id=req.request_id)
             tr.emit(f"prefill.{path}", t0, now, request_id=req.request_id,
                     share_s=dt / max(n, 1), n=n, **attrs)
-
-    def _trace_note_step(self, t_end: float, dt: float, busy: int):
-        """Accumulate one harvested decode step into the open decode
-        window; flush a ``decode.window`` span every
-        ``_TRACE_WINDOW_STEPS`` steps (windowed, never per-step spam).
-        Step intervals are contiguous chains of the inference clock
-        (``_t_mark``), so the window span's wall is exactly the sum of
-        the charged inference walls it covers."""
-        if not trace.tracer().enabled:
-            return
-        if self._win_t0 is None:
-            self._win_t0 = t_end - dt
-        self._win_end = t_end
-        self._win_steps += 1
-        self._win_busy += busy
-        if self._win_steps >= self._TRACE_WINDOW_STEPS:
-            self._trace_flush_window()
-
-    def _trace_flush_window(self):
-        """Emit and reset the open decode window (called at the step
-        threshold, before every inference-clock reset — prefill
-        admissions and idle restarts — and at drain end via
-        :meth:`trace_flush`, so a window never spans a gap)."""
-        if self._win_t0 is not None and self._win_steps:
-            # fixed thread label: window flushes can run on whichever
-            # pipeline thread resets the inference clock, but the windows
-            # themselves chain one logical timeline per engine
-            trace.tracer().emit(
-                "decode.window", self._win_t0, self._win_end,
-                thread="decode-window", steps=self._win_steps,
-                busy_slot_steps=self._win_busy, tag=self.trace_tag,
-            )
-        self._win_t0 = None
-        self._win_steps = 0
-        self._win_busy = 0
-
-    def trace_flush(self):
-        """Flush any open windowed trace state (drain boundaries)."""
-        self._trace_flush_window()
 
     # ------------------------------------------------------------------ #
     # Metrics registry (core/obs): the query plane over the ad-hoc
@@ -1265,7 +1232,14 @@ class ServingEngine:
         picked = [self.queue[i] for i in order]
         for i in sorted(order, reverse=True):
             del self.queue[i]
+        with self._trace_span("engine.admit") as sp:
+            chunking = len(self._chunk_jobs)
+            self._admit_picked(picked, free)
+            # a long prompt handed to chunked prefill is placed later, by
+            # the _chunk_step that runs its final chunk
+            sp.set(admitted=len(picked) - (len(self._chunk_jobs) - chunking))
 
+    def _admit_picked(self, picked: list, free: list):
         if self.paged:
             self._admit_paged(picked, free)
             return
@@ -1317,21 +1291,21 @@ class ServingEngine:
         self.prefill_tokens_total += int(lens[:n].sum())
         self.prefill_tokens_uncached += int(lens[:n].sum())
         self.prefill_padded_tokens += npad * L
-        t0 = time.perf_counter()
-        next_toks, cache1, lens_d = self._prefill_bucket_jit(
-            self.prefill_params, jnp.asarray(toks), jnp.asarray(lens),
-            self._next_prefill_key(),
-        )
-        art = PrefillArtifact(cache1, slot_idx, lens_d, next_toks,
-                              jnp.asarray(maxn), reqs, list(slots),
-                              n_rows=n, prefix_len=int(lens.max()))
-        art, t_xfer = self._handoff(art)  # disagg: pod-boundary KV handoff
-        self.pool.splice(art)
-        toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: 'preprocess' must include prefill device completion
+        with self._trace_prefill("bucket", L, n):
+            t0 = time.perf_counter()
+            next_toks, cache1, lens_d = self._prefill_bucket_jit(
+                self.prefill_params, jnp.asarray(toks), jnp.asarray(lens),
+                self._next_prefill_key(),
+            )
+            art = PrefillArtifact(cache1, slot_idx, lens_d, next_toks,
+                                  jnp.asarray(maxn), reqs, list(slots),
+                                  n_rows=n, prefix_len=int(lens.max()))
+            art, t_xfer = self._handoff(art)  # disagg: pod-boundary KV handoff
+            self.pool.splice(art)
+            toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: 'preprocess' must include prefill device completion
         dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
         self._prefill_shapes.add(("bucket", L))
         now = time.perf_counter()
-        self._trace_flush_window()  # decode windows never span a prefill
         self._trace_admission("bucket", reqs, t0, now, dt, n, bucket=L)
         for j, (req, slot) in enumerate(zip(reqs, slots)):
             rec = self._records[req.request_id]
@@ -1390,39 +1364,39 @@ class ServingEngine:
             self.prefill_tokens_total += total
             self.prefill_tokens_uncached += total
         self.prefill_padded_tokens += T
-        t0 = time.perf_counter()
-        next_toks, caches = self._prefill_packed_jit(
-            self.prefill_params, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(seg), jnp.asarray(seg_starts),
-            jnp.asarray(last_idx), self._next_prefill_key(),
-        )
-        self._prefill_shapes.add(("packed", T))
-        if self.paged:
-            out_w = min(T, self.max_seq)
-            dest = np.zeros((npad, out_w // self.page), np.int32)
-            for j, job in enumerate(jobs):
-                for k in range(out_w // self.page):
-                    if k < len(job.pt_row):
-                        dest[j, k] = job.pt_row[k]
-            art = PrefillArtifact(
-                caches, slot_idx, jnp.asarray(lens), next_toks,
-                jnp.asarray(maxn), reqs, list(slots),
-                n_rows=n, prefix_len=int(lens.max()),
-                dest_blocks=dest, cached_lens=np.zeros((npad,), np.int32),
-                bucket=out_w,
+        with self._trace_prefill("packed", T, n):
+            t0 = time.perf_counter()
+            next_toks, caches = self._prefill_packed_jit(
+                self.prefill_params, jnp.asarray(toks), jnp.asarray(pos),
+                jnp.asarray(seg), jnp.asarray(seg_starts),
+                jnp.asarray(last_idx), self._next_prefill_key(),
             )
-        else:
-            art = PrefillArtifact(
-                caches, slot_idx, jnp.asarray(lens), next_toks,
-                jnp.asarray(maxn), reqs, list(slots),
-                n_rows=n, prefix_len=int(lens.max()),
-            )
-        art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
-        self.pool.splice(art)
-        toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: packed 'preprocess' includes prefill device completion
+            self._prefill_shapes.add(("packed", T))
+            if self.paged:
+                out_w = min(T, self.max_seq)
+                dest = np.zeros((npad, out_w // self.page), np.int32)
+                for j, job in enumerate(jobs):
+                    for k in range(out_w // self.page):
+                        if k < len(job.pt_row):
+                            dest[j, k] = job.pt_row[k]
+                art = PrefillArtifact(
+                    caches, slot_idx, jnp.asarray(lens), next_toks,
+                    jnp.asarray(maxn), reqs, list(slots),
+                    n_rows=n, prefix_len=int(lens.max()),
+                    dest_blocks=dest, cached_lens=np.zeros((npad,), np.int32),
+                    bucket=out_w,
+                )
+            else:
+                art = PrefillArtifact(
+                    caches, slot_idx, jnp.asarray(lens), next_toks,
+                    jnp.asarray(maxn), reqs, list(slots),
+                    n_rows=n, prefix_len=int(lens.max()),
+                )
+            art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
+            self.pool.splice(art)
+            toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: packed 'preprocess' includes prefill device completion
         dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
         now = time.perf_counter()
-        self._trace_flush_window()
         self._trace_admission("packed", reqs, t0, now, dt, n, packed_width=T)
         for j, (req, slot) in enumerate(zip(reqs, slots)):
             rec = self._records[req.request_id]
@@ -1465,7 +1439,12 @@ class ServingEngine:
         """
         if not self._chunk_jobs:
             return
-        job = self._chunk_jobs[0]
+        with self._trace_span("engine.admit") as sp:
+            sp.set(admitted=int(self._chunk_one(self._chunk_jobs[0])))
+
+    def _chunk_one(self, job: _ChunkJob) -> bool:
+        """One chunk of ``job``; True when it was the final chunk and the
+        request took its slot."""
         C = self.prefill_chunk
         P = len(job.req.prompt_tokens)
         t0 = time.perf_counter()
@@ -1478,57 +1457,58 @@ class ServingEngine:
         n = ((P - 1) % C) + 1 if job.done == 0 else C
         toks = np.zeros((1, C), np.int32)
         toks[0, :n] = job.req.prompt_tokens[job.done:job.done + n]
-        next_tok, job.prior = self._chunk_jit(
-            self.prefill_params, job.prior, jnp.asarray(toks),
-            jnp.asarray(np.asarray([n], np.int32)),
-            jnp.asarray(np.asarray([job.done], np.int32)),
-            self._next_prefill_key(),
-        )
-        job.done += n
-        self.prefill_padded_tokens += C
-        self._prefill_shapes.add(("chunk", C))
-        if job.done < P:
-            np.asarray(next_tok)  # reprolint: disable=RL001 deliberate fence: chunk 'preprocess' includes device completion (and bounds host run-ahead to one chunk)
-            t1 = time.perf_counter()
-            rec.add("preprocess", max(t1 - t0, 0.0))
-            trace.tracer().emit("prefill.chunk", t0, t1,
-                                request_id=job.req.request_id,
-                                chunk=C, done=job.done, prompt=P)
-            return
-        # final chunk: shape the prior into a standard bucketed-style
-        # artifact (row dim padded to npad, OOB dummy rows) and splice
-        self._chunk_jobs.popleft()
-        self._chunk_slots.discard(job.slot)
-        npad = self.max_batch
-        caches = self._chunk_pad_jit(job.prior)
-        job.prior = None  # donated away
-        slot_idx = np.full((npad,), npad, np.int32)
-        slot_idx[0] = job.slot
-        lens = np.zeros((npad,), np.int32)
-        lens[0] = P
-        maxn = np.zeros((npad,), np.int32)
-        maxn[0] = job.req.max_new_tokens
-        tok0 = int(np.asarray(next_tok)[0])  # reprolint: disable=RL001 deliberate fence: final-chunk 'preprocess' includes device completion
-        next_full = np.zeros((npad,), np.int32)
-        next_full[0] = tok0
-        art = PrefillArtifact(
-            caches, slot_idx, jnp.asarray(lens), jnp.asarray(next_full),
-            jnp.asarray(maxn), [job.req], [job.slot],
-            n_rows=1, prefix_len=P,
-        )
-        art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
-        self.pool.splice(art)
-        dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
+        with self._trace_prefill("chunk", C, 1):
+            next_tok, job.prior = self._chunk_jit(
+                self.prefill_params, job.prior, jnp.asarray(toks),
+                jnp.asarray(np.asarray([n], np.int32)),
+                jnp.asarray(np.asarray([job.done], np.int32)),
+                self._next_prefill_key(),
+            )
+            job.done += n
+            self.prefill_padded_tokens += C
+            self._prefill_shapes.add(("chunk", C))
+            if job.done < P:
+                np.asarray(next_tok)  # reprolint: disable=RL001 deliberate fence: chunk 'preprocess' includes device completion (and bounds host run-ahead to one chunk)
+                t1 = time.perf_counter()
+                rec.add("preprocess", max(t1 - t0, 0.0))
+                trace.tracer().emit("prefill.chunk", t0, t1,
+                                    request_id=job.req.request_id,
+                                    chunk=C, done=job.done, prompt=P)
+                return False
+            # final chunk: shape the prior into a standard bucketed-style
+            # artifact (row dim padded to npad, OOB dummy rows) and splice
+            self._chunk_jobs.popleft()
+            self._chunk_slots.discard(job.slot)
+            npad = self.max_batch
+            caches = self._chunk_pad_jit(job.prior)
+            job.prior = None  # donated away
+            slot_idx = np.full((npad,), npad, np.int32)
+            slot_idx[0] = job.slot
+            lens = np.zeros((npad,), np.int32)
+            lens[0] = P
+            maxn = np.zeros((npad,), np.int32)
+            maxn[0] = job.req.max_new_tokens
+            tok0 = int(np.asarray(next_tok)[0])  # reprolint: disable=RL001 deliberate fence: final-chunk 'preprocess' includes device completion
+            next_full = np.zeros((npad,), np.int32)
+            next_full[0] = tok0
+            art = PrefillArtifact(
+                caches, slot_idx, jnp.asarray(lens), jnp.asarray(next_full),
+                jnp.asarray(maxn), [job.req], [job.slot],
+                n_rows=1, prefix_len=P,
+            )
+            art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
+            self.pool.splice(art)
+            dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
         rec.add("preprocess", dt)
         job.req.generated.append(tok0)
         now = time.perf_counter()
-        self._trace_flush_window()
         trace.tracer().emit("prefill.chunk", t0, now,
                             request_id=job.req.request_id,
                             chunk=C, done=job.done, prompt=P, final=True)
         job.req.t_first_token = now
         self._place(job.req, job.slot)
         self._t_mark = now  # chunk time is "preprocess", not "inference"
+        return True
 
     def _prefill_exact(self, slot: int, req: Request):
         """Exact-shape prefill for feature-carrying (vlm/audio) requests."""
@@ -1539,27 +1519,28 @@ class ServingEngine:
         self.prefill_tokens_total += len(req.prompt_tokens)
         self.prefill_tokens_uncached += len(req.prompt_tokens)
         self.prefill_padded_tokens += len(req.prompt_tokens)
-        t0 = time.perf_counter()
-        logits, cache1, lengths1 = self._prefill_exact_jit(
-            self.prefill_params, batch
-        )
-        # eager sample (the exact path compiles per ragged shape anyway);
-        # temperature 0 stays the argmax baseline bit-for-bit
-        next_tok = self.pool._sample(logits, self._next_prefill_key())
-        # feature frames (vlm) prepend to the token sequence, so the cache's
-        # true length is frames + prompt — len(prompt_tokens) alone would
-        # let a pod handoff slice live KV off the wire. Derived host-side
-        # (no device sync on the single-node hot path); the disagg feature
-        # regression test pins it against the model-returned lengths.
-        frames = 0 if req.features is None else int(np.shape(req.features)[-2])
-        art = PrefillArtifact(
-            cache1, np.asarray([slot], np.int32), lengths1, next_tok,
-            jnp.asarray([req.max_new_tokens], jnp.int32), [req], [slot],
-            n_rows=1, prefix_len=len(req.prompt_tokens) + frames,
-        )
-        art, t_xfer = self._handoff(art)
-        self.pool.splice(art)
-        tok_host = int(np.asarray(art.next_tokens)[0])  # reprolint: disable=RL001 deliberate fence: exact-path 'preprocess' includes device completion
+        with self._trace_prefill("exact", toks.shape[1], 1):
+            t0 = time.perf_counter()
+            logits, cache1, lengths1 = self._prefill_exact_jit(
+                self.prefill_params, batch
+            )
+            # eager sample (the exact path compiles per ragged shape anyway);
+            # temperature 0 stays the argmax baseline bit-for-bit
+            next_tok = self.pool._sample(logits, self._next_prefill_key())
+            # feature frames (vlm) prepend to the token sequence, so the cache's
+            # true length is frames + prompt — len(prompt_tokens) alone would
+            # let a pod handoff slice live KV off the wire. Derived host-side
+            # (no device sync on the single-node hot path); the disagg feature
+            # regression test pins it against the model-returned lengths.
+            frames = 0 if req.features is None else int(np.shape(req.features)[-2])
+            art = PrefillArtifact(
+                cache1, np.asarray([slot], np.int32), lengths1, next_tok,
+                jnp.asarray([req.max_new_tokens], jnp.int32), [req], [slot],
+                n_rows=1, prefix_len=len(req.prompt_tokens) + frames,
+            )
+            art, t_xfer = self._handoff(art)
+            self.pool.splice(art)
+            tok_host = int(np.asarray(art.next_tokens)[0])  # reprolint: disable=RL001 deliberate fence: exact-path 'preprocess' includes device completion
         dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
         self._prefill_shapes.add(
             ("exact", toks.shape[1],
@@ -1570,7 +1551,6 @@ class ServingEngine:
         rec.add("preprocess", dt)
         req.generated.append(tok_host)
         req.t_first_token = time.perf_counter()
-        self._trace_flush_window()
         self._trace_admission("exact", [req], t0, req.t_first_token, dt, 1,
                               prompt=len(req.prompt_tokens))
         self._place(req, slot)
@@ -1746,42 +1726,42 @@ class ServingEngine:
                     dest[j, k] = job.pt_row[cpages + k]
             prior_pt[j, : len(job.p_ids)] = job.p_ids
         self.prefill_padded_tokens += npad * L
-        t0 = time.perf_counter()
-        key = self._next_prefill_key()
-        if has_prior:
-            next_toks, cacheL, lens_d = self._prefill_suffix_jit(
-                self.prefill_params, self._prior_blocks(),
-                jnp.asarray(prior_pt), jnp.asarray(toks), jnp.asarray(lens),
-                jnp.asarray(cached), key,
+        with self._trace_prefill("suffix" if has_prior else "paged", L, n):
+            t0 = time.perf_counter()
+            key = self._next_prefill_key()
+            if has_prior:
+                next_toks, cacheL, lens_d = self._prefill_suffix_jit(
+                    self.prefill_params, self._prior_blocks(),
+                    jnp.asarray(prior_pt), jnp.asarray(toks), jnp.asarray(lens),
+                    jnp.asarray(cached), key,
+                )
+                self._prefill_shapes.add(("suffix", L))
+                # the p-pins held the gathered prior across the dispatch; the
+                # page-table row (d-side) keeps the row's own hold from here
+                for job in jobs:
+                    self._store_deref(job.p_ids)
+            else:
+                next_toks, cacheL, lens_d = self._prefill_paged_jit(
+                    self.prefill_params, jnp.asarray(toks), jnp.asarray(lens),
+                    key,
+                )
+                self._prefill_shapes.add(("paged", L))
+            store_ctx = self._store_prepare(jobs, cacheL, L)
+            art = PrefillArtifact(
+                cacheL, slot_idx, lens_d, next_toks, jnp.asarray(maxn),
+                [job.req for job in jobs], [job.slot for job in jobs],
+                n_rows=n, prefix_len=int((cached + lens).max()),
+                dest_blocks=dest, cached_lens=cached, bucket=L,
             )
-            self._prefill_shapes.add(("suffix", L))
-            # the p-pins held the gathered prior across the dispatch; the
-            # page-table row (d-side) keeps the row's own hold from here
-            for job in jobs:
-                self._store_deref(job.p_ids)
-        else:
-            next_toks, cacheL, lens_d = self._prefill_paged_jit(
-                self.prefill_params, jnp.asarray(toks), jnp.asarray(lens),
-                key,
-            )
-            self._prefill_shapes.add(("paged", L))
-        store_ctx = self._store_prepare(jobs, cacheL, L)
-        art = PrefillArtifact(
-            cacheL, slot_idx, lens_d, next_toks, jnp.asarray(maxn),
-            [job.req for job in jobs], [job.slot for job in jobs],
-            n_rows=n, prefix_len=int((cached + lens).max()),
-            dest_blocks=dest, cached_lens=cached, bucket=L,
-        )
-        art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
-        self.pool.splice(art)
-        toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: paged 'preprocess' includes prefill device completion
+            art, t_xfer = self._handoff(art)  # disagg: pod-boundary handoff
+            self.pool.splice(art)
+            toks_host = np.asarray(art.next_tokens)  # reprolint: disable=RL001 deliberate fence: paged 'preprocess' includes prefill device completion
         dt = max(time.perf_counter() - t0 - t_xfer, 0.0)
         # index the prompts' pages BEFORE the records loop: a request the
         # prefill token already finishes releases its slot there, and the
         # index must take its block references first
         self._index_insert(jobs, store_ctx)
         now = time.perf_counter()
-        self._trace_flush_window()
         self._trace_admission(
             "suffix" if has_prior else "paged",
             [job.req for job in jobs], t0, now, dt, n, bucket=L,
@@ -1840,11 +1820,14 @@ class ServingEngine:
             return
         if not self.pool.window and outstanding == 0:
             # pipeline (re)start: don't charge idle time to "inference"
-            self._trace_flush_window()  # a window never spans an idle gap
             self._t_mark = time.perf_counter()
         limit = self._window_limit()
-        while self.pool.fill_one(self.decode_params, limit=limit):
-            self.decode_steps += 1
+        with self._trace_span("engine.dispatch") as sp:
+            steps = 0
+            while self.pool.fill_one(self.decode_params, limit=limit):
+                steps += 1
+            self.decode_steps += steps
+            sp.set(steps=steps)
 
     def _harvest(self) -> list[Response]:
         """Synchronous harvest: device transfer + finalize in one call (the
@@ -1855,11 +1838,14 @@ class ServingEngine:
         e = self.pool.pop_oldest()
         if e is None:
             return []
-        toks, _done = jax.device_get((e.tokens, e.done))  # one host transfer
-        now = time.perf_counter()
-        dt = max(now - self._t_mark, 0.0)
-        self._t_mark = now
-        return self._finalize_harvest(e, toks, dt)
+        live = sum(1 for r in e.slots
+                   if r is not None and r.request_id not in self._finished_ids)
+        with self._trace_span("engine.harvest", live=live):
+            toks, _done = jax.device_get((e.tokens, e.done))  # one host transfer
+            now = time.perf_counter()
+            dt = max(now - self._t_mark, 0.0)
+            self._t_mark = now
+            return self._finalize_harvest(e, toks, dt)
 
     def _finalize_harvest(self, e: _InFlight, toks, dt: float) -> list[Response]:
         """Detokenize/record-finalize stage: pure host bookkeeping over one
@@ -1872,7 +1858,6 @@ class ServingEngine:
         ]
         if live:
             self.useful_steps += 1
-        self._trace_note_step(self._t_mark, dt, len(live))
         done: list[Response] = []
         for i, req in live:
             rec = self._records[req.request_id]
@@ -1954,19 +1939,20 @@ class ServingEngine:
         ``inflight`` steps behind the device and never blocks on the newest
         work.
         """
-        if self.legacy:
-            return self._step_legacy()
-        self._admit()
-        self._dispatch()
-        # one chunk AFTER the decode top-up: live slots' steps are already
-        # on the device stream, so the chunk interleaves instead of
-        # head-of-line blocking a full prefill
-        self._chunk_step()
-        done = self._harvest()
-        if self._prefill_finished:  # budget met by the prefill token itself
-            done = self._prefill_finished + done
-            self._prefill_finished = []
-        return done
+        with self._trace_span("engine.step"):
+            if self.legacy:
+                return self._step_legacy()
+            self._admit()
+            self._dispatch()
+            # one chunk AFTER the decode top-up: live slots' steps are
+            # already on the device stream, so the chunk interleaves
+            # instead of head-of-line blocking a full prefill
+            self._chunk_step()
+            done = self._harvest()
+            if self._prefill_finished:  # budget met by the prefill token
+                done = self._prefill_finished + done
+                self._prefill_finished = []
+            return done
 
     @property
     def idle(self) -> bool:
@@ -1982,7 +1968,6 @@ class ServingEngine:
             out.extend(self.step())
             if self.idle:
                 break
-        self.trace_flush()
         return out
 
     # ------------------------------------------------------------------ #
@@ -2052,32 +2037,33 @@ class ServingEngine:
         active = [i for i, s in enumerate(self.pool.slots) if s is not None]
         if not active:
             return []
-        t0 = time.perf_counter()
-        logits, self.caches, self.lengths = self._decode(
-            self.params, self.caches, self.tokens, self.lengths
-        )
-        self.decode_steps += 1
-        self.useful_steps += 1  # sync loop only ever steps live slots
-        logits.block_until_ready()
-        dt = time.perf_counter() - t0
-        self._trace_note_step(t0 + dt, dt, len(active))
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
-        self.tokens = jnp.asarray(next_tokens[:, None], jnp.int32)
-
-        done: list[Response] = []
-        for i in active:
-            req = self.pool.slots[i]
-            rec = self._records[req.request_id]
-            rec.add("inference", dt / max(len(active), 1))
-            tok = int(next_tokens[i])
-            req.generated.append(tok)
-            finished = len(req.generated) >= req.max_new_tokens or (
-                self.eos is not None and tok == self.eos
+        # the synchronous loop's decode step is its own harvest
+        with self._trace_span("engine.harvest", live=len(active)):
+            t0 = time.perf_counter()
+            logits, self.caches, self.lengths = self._decode(
+                self.params, self.caches, self.tokens, self.lengths
             )
-            if finished:
-                done.append(self._finish(req, rec))
-                self.pool.slots[i] = None
-        return done
+            self.decode_steps += 1
+            self.useful_steps += 1  # sync loop only ever steps live slots
+            logits.block_until_ready()
+            dt = time.perf_counter() - t0
+            next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+            self.tokens = jnp.asarray(next_tokens[:, None], jnp.int32)
+
+            done: list[Response] = []
+            for i in active:
+                req = self.pool.slots[i]
+                rec = self._records[req.request_id]
+                rec.add("inference", dt / max(len(active), 1))
+                tok = int(next_tokens[i])
+                req.generated.append(tok)
+                finished = len(req.generated) >= req.max_new_tokens or (
+                    self.eos is not None and tok == self.eos
+                )
+                if finished:
+                    done.append(self._finish(req, rec))
+                    self.pool.slots[i] = None
+            return done
 
 
 class EnginePipeline:
@@ -2199,23 +2185,26 @@ class EnginePipeline:
         eng = self.engine
         while not self._stop.is_set():
             entry = None
-            t0 = time.perf_counter()
-            with self._lock:
+            with self._lock, (
+                    # an idle poll is no dispatch: it opens no span
+                    contextlib.nullcontext() if eng.idle else
+                    trace.span("pipeline.dispatch", tag="pipeline")):
                 eng._admit()
+                eng._dispatch(outstanding=self._outstanding)
+                eng._chunk_step()  # one chunk behind the decode top-up
+                # after the chunk step, under the same lock: a final chunk
+                # whose budget its prefill token met is emitted before
+                # the facade can see the engine idle
                 if eng._prefill_finished:  # budget met at prefill time
                     done = list(eng._prefill_finished)
                     eng._prefill_finished = []
                     self._outputs.extend(done)
                     self.emitted += len(done)
-                eng._dispatch(outstanding=self._outstanding)
-                eng._chunk_step()  # one chunk behind the decode top-up
                 if eng.pool.window:
                     entry = eng.pool.pop_oldest()
                     eng._backlog_entries.append(entry)
                     self._outstanding += 1
             if entry is not None:
-                trace.tracer().emit("pipeline.dispatch", t0,
-                                    time.perf_counter(), tag="pipeline")
                 # NEVER under the lock: a full backlog must block dispatch
                 # without blocking the detokenize thread's finalize
                 self._put(self._harvest_q, entry)
@@ -2229,10 +2218,9 @@ class EnginePipeline:
                 continue
             # the blocking device->host transfer, off every other thread's
             # critical path (no lock: snapshot arrays are read-only here)
-            t0 = time.perf_counter()
-            toks, _done = jax.device_get((entry.tokens, entry.done))
+            with trace.span("pipeline.harvest", tag="pipeline"):
+                toks, _done = jax.device_get((entry.tokens, entry.done))
             t_h = time.perf_counter()
-            trace.tracer().emit("pipeline.harvest", t0, t_h, tag="pipeline")
             self._put(self._detok_q, (entry, toks, t_h))
 
     def _detok_loop(self):
@@ -2242,8 +2230,8 @@ class EnginePipeline:
             if item is None:
                 continue
             entry, toks, t_h = item
-            t0 = time.perf_counter()
-            with self._lock:
+            with trace.span("pipeline.detokenize", tag="pipeline"), \
+                    self._lock:
                 # FIFO edges: the entry being finalized is always the
                 # oldest backlog entry; drop it BEFORE finalize so the
                 # _finished_ids prune is tight
@@ -2259,8 +2247,6 @@ class EnginePipeline:
                 self._outputs.extend(done)
                 self.emitted += len(done)
                 self._outstanding -= 1
-            trace.tracer().emit("pipeline.detokenize", t0,
-                                time.perf_counter(), tag="pipeline")
 
     # ------------------------------------------------------------------ #
     # step()-compatible facade
@@ -2303,13 +2289,7 @@ class EnginePipeline:
             if self.idle:
                 break
             time.sleep(self.poll_s)
-        self.trace_flush()
         return out
-
-    def trace_flush(self):
-        """Flush the engine's open decode-window span (drain boundary)."""
-        with self._lock:
-            self.engine._trace_flush_window()
 
     def load_snapshot(self) -> dict:
         """Router-visible load + conservation counters, read atomically

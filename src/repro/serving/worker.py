@@ -133,7 +133,6 @@ def _drain(pipe, deadline_s: float) -> dict:
     for rsp in pipe.step():  # finals surfaced by the last transition to idle
         rec = pipe.engine._records[rsp.request_id]
         done.append((ipc.response_to_wire(rsp), ipc.record_to_wire(rec)))
-    pipe.trace_flush()  # close the open decode window before shipping
     return {"done": done, "load": _snapshot(pipe), "spans": _spans()}
 
 

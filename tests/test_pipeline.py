@@ -89,6 +89,28 @@ def test_pipeline_records_complete(served):
             assert "queue" in rec.stage_s
 
 
+def test_pipeline_drains_chunked_request_met_at_prefill(served):
+    """A chunked prompt whose budget its prefill token meets is placed by
+    the final chunk step with nothing left to decode: the pipeline must
+    still emit it before it reports idle."""
+    from repro.serving.engine import EnginePipeline
+
+    cfg, model, params = served
+    reqs = make_requests(cfg, [40, 8], 1, seed=3)
+    base = _engine(model, params, prefill_chunk=16)
+    for r in make_requests(cfg, [40, 8], 1, seed=3):
+        base.submit(r)
+    want = sorted(r.tokens for r in base.run_until_drained())
+    eng = _engine(model, params, prefill_chunk=16)
+    with EnginePipeline(eng) as pipe:
+        for r in reqs:
+            pipe.submit(r)
+        out = pipe.run_until_drained(max_steps=200_000)
+        assert pipe.idle
+        assert sorted(r.tokens for r in out) == want
+        assert len(out) == len(reqs) == pipe.emitted
+
+
 def test_pipeline_thread_failure_surfaces(served):
     """A crash on a pipeline thread must re-raise on the caller's next
     touch (with the worker traceback), never hang the facade."""

@@ -486,8 +486,31 @@ def test_rl007_silent_with_emit_or_trace_helper():
         def _finish(self, rec, entry):
             t0 = time.perf_counter()
             rec.add("inference", time.perf_counter() - t0)
-            self._trace_flush_window(entry)
+            self._trace_admission(entry)
     """) == []
+    # a trace.span(...) context: the span is emitted on exit
+    assert lint("""
+        import time
+        from repro.core import trace
+
+        def _harvest(self, rec, entry):
+            with trace.span("engine.harvest", live=1):
+                t0 = time.perf_counter()
+                rec.add("inference", time.perf_counter() - t0)
+    """) == []
+
+
+def test_rl007_drain_hook_is_not_a_span():
+    # a drain hook is no span emitter: the charge stays untraced
+    found = lint("""
+        import time
+
+        def _finish(self, rec, entry):
+            t0 = time.perf_counter()
+            rec.add("inference", time.perf_counter() - t0)
+            self.trace_flush()
+    """)
+    assert codes(found) == ["RL007"]
 
 
 def test_rl007_scoped_to_hot_files_and_untimed_functions():

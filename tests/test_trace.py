@@ -76,6 +76,46 @@ def test_buffer_enable_reset_and_drain():
     assert buf.snapshot() == [] and buf.stats()["emitted"] == 0
 
 
+def test_span_ring_off_writes_nothing(monkeypatch):
+    buf = TraceBuffer(capacity=8)
+    monkeypatch.setattr(trace, "_GLOBAL", buf)
+    with trace.span("engine.step", tag="engine") as sp:
+        sp.set(admitted=1)
+    assert buf.snapshot() == [] and buf.stats()["emitted"] == 0
+
+
+def test_span_writes_ring_and_profiler(tmp_path):
+    """One span lands in the ring (perf_counter stamps, attrs incl. the
+    ones set on the way) and in the profiler's host plane under the same
+    name with the attrs as event stats."""
+    import glob
+
+    import jax
+
+    trace.enable_tracing(process="main")
+    jax.profiler.start_trace(str(tmp_path))
+    t0 = time.perf_counter()
+    with trace.span("engine.prefill", path="bucket", bucket=128) as sp:
+        sp.set(live_rows=3)
+    t1 = time.perf_counter()
+    jax.profiler.stop_trace()
+    [got] = trace.tracer().snapshot()
+    assert got.name == "engine.prefill" and got.request_id is None
+    assert t0 <= got.t_start <= got.t_end <= t1
+    assert got.attrs == {"path": "bucket", "bucket": 128, "live_rows": 3}
+
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [
+        (e.name, dict(e.stats))
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name.startswith("engine.")
+    ]
+    assert events == [("engine.prefill",
+                       {"path": "bucket", "bucket": 128, "live_rows": 3})]
+
+
 # --------------------------------------------------------------------------- #
 # wire round-trip + adversarial skew rebasing (the IPC span ferry)
 # --------------------------------------------------------------------------- #
@@ -88,7 +128,7 @@ def test_wire_roundtrip_rebases_onto_parent_clock(offset):
     worker.enable()
     t0 = 1000.0 + offset  # worker-clock stamp of a parent-clock t=1000
     worker.emit("prefill.bucket", t0, t0 + 0.25, request_id=1)
-    worker.emit("decode.window", t0 + 0.25, t0 + 0.75, request_id=1)
+    worker.emit("engine.harvest", t0 + 0.25, t0 + 0.75, request_id=1)
 
     parent = TraceBuffer(capacity=16, process="router")
     parent.ingest_wire(worker.drain_wire(), offset=offset, process="replica0")
@@ -188,6 +228,40 @@ def test_engine_drain_reconciles_and_trees_are_wellformed(model_bank):
         assert name in summary
 
 
+def test_engine_phase_spans_nest_in_each_step(model_bank):
+    """Every engine.* phase of a drain sits inside an engine.step span,
+    with the attrs the benchmark's readers use."""
+    eng, _out, tr = _traced_drain(model_bank)
+    eng_spans = [s for s in tr.spans if s.name.startswith("engine.")]
+    by_name: dict = {}
+    for s in eng_spans:
+        by_name.setdefault(s.name, []).append(s)
+    assert set(by_name) == {"engine.step", "engine.admit", "engine.prefill",
+                            "engine.dispatch", "engine.harvest"}
+    steps = by_name["engine.step"]
+    for s in eng_spans:
+        assert s.request_id is None and s.attrs["tag"] == "engine"
+        if s.name != "engine.step":
+            assert any(p.t_start <= s.t_start and s.t_end <= p.t_end
+                       for p in steps), s
+    # 4 requests over 2 slots: every admission placed one or two of them
+    admitted = [s.attrs["admitted"] for s in by_name["engine.admit"]]
+    assert sum(admitted) == 4 and all(a >= 1 for a in admitted)
+    for s in by_name["engine.prefill"]:
+        assert s.attrs["path"] == "bucket" and s.attrs["rows"] >= 1
+        assert 0 <= s.attrs["live_rows"] <= 2 - s.attrs["rows"]
+        assert s.attrs["bucket"] in (16, 32, 64)
+    # the first prefill finds no live decode row; a later one does
+    assert by_name["engine.prefill"][0].attrs["live_rows"] == 0
+    assert any(s.attrs["live_rows"] > 0 for s in by_name["engine.prefill"])
+    assert sum(s.attrs["steps"] for s in by_name["engine.dispatch"]) == \
+        eng.decode_steps
+    # one harvest per step that found an in-flight decode step
+    harvests = by_name["engine.harvest"]
+    assert len(harvests) == eng.decode_steps
+    assert sum(s.attrs["live"] > 0 for s in harvests) == eng.useful_steps
+
+
 def test_trace_shape_deterministic_across_seeded_runs(model_bank):
     """Same seed, fresh engine -> same span tree SHAPE (request ids and
     stamps differ run to run; the structure must not)."""
@@ -248,6 +322,24 @@ def test_reconcile_flags_uncovered_charge_and_malformed_trees():
     tr3 = Trace([span("transfer", 0.0, 1.0, rid=None),
                  span("transfer", 0.5, 1.5, rid=None)])
     assert tr3.tree_problems()
+    # nested phases on one lane are one call stack: well formed
+    nested = Trace([span("engine.step", 0.0, 1.0, rid=None),
+                    span("engine.admit", 0.1, 0.6, rid=None),
+                    span("engine.prefill", 0.2, 0.5, rid=None),
+                    span("engine.harvest", 0.6, 0.9, rid=None),
+                    span("engine.step", 1.0, 2.0, rid=None)])
+    assert nested.tree_problems() == []
+    # ... but a child that outlives its parent crosses it
+    crossing = Trace(nested.spans + [span("engine.harvest", 0.95, 1.2,
+                                          rid=None)])
+    assert any("'engine.harvest' starts" in p
+               for p in crossing.tree_problems())
+    # a phase inside one of its own name is a mis-stamp, however it nests
+    for inner in (span("engine.step", 0.2, 0.4, rid=None),
+                  span("engine.prefill", 0.3, 0.4, rid=None)):
+        renested = Trace(nested.spans + [inner])
+        assert any(f"nests inside another '{inner.name}'" in p
+                   for p in renested.tree_problems())
     # same intervals on distinct lanes (tag attr) are fine
     s1 = span("transfer", 0.0, 1.0, rid=None)
     s1.attrs["tag"] = "replica0"

@@ -674,15 +674,14 @@ def rl006(mod: Module, ctx: Context) -> list:
 # --------------------------------------------------------------------------- #
 def _emits_trace(mod: Module, fn: ast.AST) -> bool:
     """True when the function body reaches a span emitter: a ``.emit()``
-    call (``trace.tracer().emit(...)``) or a call to a ``_trace*`` /
-    ``trace_flush`` helper (the engine's admission/window emitters)."""
+    call (``trace.tracer().emit(...)``), a ``trace.span(...)`` context,
+    or a call to a ``_trace*`` helper (the engine's span emitters)."""
     for node in _walk_local(fn):
         if not isinstance(node, ast.Call):
             continue
         if isinstance(node.func, ast.Attribute):
             attr = node.func.attr
-            if attr == "emit" or attr == "trace_flush" \
-                    or attr.startswith("_trace"):
+            if attr in ("emit", "span") or attr.startswith("_trace"):
                 return True
         elif isinstance(node.func, ast.Name) \
                 and node.func.id.startswith("_trace"):
@@ -693,7 +692,7 @@ def _emits_trace(mod: Module, fn: ast.AST) -> bool:
 @rule(
     "RL007", "trace-coverage",
     "every timed-stage function in a hot-path file also emits a span "
-    "(directly via .emit() or through a _trace* helper) so charged "
+    "(via .emit(), a trace.span() context or a _trace* helper) so charged "
     "stages stay reconcilable against the trace",
     interested=_in_hot_file,
 )
@@ -708,7 +707,8 @@ def rl007(mod: Module, ctx: Context) -> list:
             "RL007", mod.rel, fn.lineno, qual,
             f"timed-stage function `{qual}` charges a stage but emits no "
             f"span — Trace.reconcile() cannot cross-check its charge "
-            f"(call trace.tracer().emit(...) or a _trace* helper, or "
+            f"(call trace.tracer().emit(...), open trace.span(...) or "
+            f"call a _trace* helper, or "
             f"suppress with the reason the stage is trace-exempt)",
         ))
     return findings
