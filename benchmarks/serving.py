@@ -177,7 +177,7 @@ def bench_packed_prefill(quick: bool):
             ),
         }, [tuple(r.generated) for r in reqs]
 
-    bucketed, toks_b = footprint()
+    bucketed, toks_b = footprint(packed=False)
     packed, toks_p = footprint(packed=True)
     assert toks_p == toks_b, "packed prefill changed generated tokens"
     assert (

@@ -84,7 +84,7 @@ from repro.core import trace
 from repro.core.metrics import jain_index, merge_record_streams, slo_summary
 from repro.core.obs import Registry, Sampler
 from repro.core.profiler import ProfileStore, RequestRecord
-from repro.serving.engine import ServingEngine, _next_pow2
+from repro.serving.engine import ServingEngine, _next_pow2, resolve_packed
 
 
 def replica_pod_slices(n_pods: int, n_replicas: int,
@@ -229,7 +229,15 @@ class _RemoteEngineFacade:
         self.max_seq = int(kw.get("max_seq", 256))
         self.max_batch = int(kw.get("max_batch", 8))
         self.page = int(kw.get("page_size", 16))
-        self.packed = bool(kw.get("packed", False))
+        # derived as the worker's engine derives it, so the router's
+        # bucket key matches the path the worker takes
+        self.packed = resolve_packed(
+            spec["cfg"], kw.get("packed"),
+            bucketed_prefill=self.bucketed_prefill,
+            legacy=bool(kw.get("legacy", False)),
+            paged=bool(kw.get("paged", False)),
+            prefix_reuse=bool(kw.get("prefix_reuse", True)),
+        )
         self.prefill_chunk = int(kw.get("prefill_chunk", 0))
 
     def _bucket(self, s: int) -> int:

@@ -30,7 +30,8 @@ Fast path (the serving hot loop, rebuilt for throughput):
   up to max_batch x prefill FLOPs on sparse admissions for exactly one
   compile per bucket; dummy rows scatter out-of-bounds and drop). Compile
   count is O(log max_seq) instead of O(distinct prompt lengths), and an
-  admission burst is a single device dispatch.
+  admission burst is a single device dispatch. Kept for paged pools that
+  reuse prefixes and as the ``packed=False`` A/B baseline.
 * **Device-resident decode loop** — sampling (greedy argmax by default;
   temperature/top-k categorical with an in-jit threaded PRNG key when
   ``temperature > 0``), EOS detection, per-slot done flags, and length
@@ -45,14 +46,19 @@ Fast path (the serving hot loop, rebuilt for throughput):
 * **Fused admission splice** — growing a prefill cache to the pool window
   and scattering it into the free slots (plus lengths/tokens/flag updates)
   is one jitted, donated call instead of a per-leaf ``.at[].set`` chain.
-* **Token-packed prefill** (``packed=True``) — instead of right-padding
-  each prompt to its bucket, an admission concatenates every prompt into
-  ONE ``[1, pow2(total_true_tokens)]`` sequence with per-token segment
-  ids (cross-prompt attention masked in the kernel, positions
-  segment-relative), so a ragged admission's prefill cost tracks the
-  tokens it actually has. The packed cache unpacks per segment in-jit
-  into the same bucketed-shaped artifact, and ``packed=False`` keeps the
-  bucketed path as the measured A/B baseline.
+* **Token-packed prefill** (the default wherever it is sound) — instead
+  of right-padding each prompt to its bucket, an admission concatenates
+  every prompt into ONE ``[1, pow2(total_true_tokens)]`` sequence with
+  per-token segment ids (cross-prompt attention masked in the kernel,
+  positions segment-relative), so a ragged admission's prefill cost
+  tracks the tokens it actually has. The packed cache unpacks per
+  segment in-jit into the same bucketed-shaped artifact. Left unset,
+  ``packed`` is derived (:func:`resolve_packed`): every attention-only,
+  non-MLA stack packs unless its paged pool reuses prefixes (packed
+  pages interleave segments, so reuse keeps the bucket path);
+  ``packed=False`` keeps the bucketed path as the measured A/B baseline.
+  The packed width is capped at ``pow2(max_seq)``, one longest prompt's
+  width; an admission past the cap goes out as consecutive packed calls.
 * **Chunked prefill** (``prefill_chunk=C``) — prompts longer than C admit
   as fixed-width suffix-prefill chunks, ONE per engine iteration after
   the decode window top-up, so a long admission interleaves with live
@@ -97,6 +103,7 @@ from repro.core.profiler import ProfileStore, RequestRecord
 from repro.core.transport import PAPER_A2, Transport, TransportProfile
 from repro.models import Model
 from repro.models import kvcache as kvc
+from repro.models.transformer import layer_groups
 from repro.serving.prefix import RadixPrefixIndex
 from repro.serving.request import Request, Response
 
@@ -120,6 +127,30 @@ WARM_PRETRACE_TABLE = frozenset({
 
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
+
+
+def _attention_only(cfg) -> bool:
+    return all(kind == "attn" for g in layer_groups(cfg) for (kind, _) in g.sigs)
+
+
+def resolve_packed(cfg, packed: Optional[bool], *,
+                   bucketed_prefill: bool = True, legacy: bool = False,
+                   paged: bool = False, prefix_reuse: bool = True) -> bool:
+    """Whether an engine built with these options packs its admissions.
+
+    Packing shares the bucketed path's soundness gate (an attention-only
+    stack off the legacy loop) and needs a non-MLA stack: segment masking
+    rides the plain-score attention path. Where that holds, an explicit
+    ``packed`` is taken as given, and ``None`` packs unless the paged pool
+    reuses prefixes — packed pages interleave segments, so packing would
+    silently switch reuse off.
+    """
+    if (not (bucketed_prefill and not legacy and _attention_only(cfg))
+            or cfg.mla is not None):
+        return False
+    if packed is None:
+        return not (paged and prefix_reuse)
+    return bool(packed)
 
 
 @dataclasses.dataclass
@@ -569,7 +600,7 @@ class ServingEngine:
         page_size: int = 16,
         cache_blocks: Optional[int] = None,
         prefix_reuse: bool = True,
-        packed: bool = False,
+        packed: Optional[bool] = None,
         prefill_chunk: int = 0,
         debug_stamps: bool = False,
         trace_tag: str = "engine",
@@ -590,10 +621,9 @@ class ServingEngine:
         # cannot leak into cached state: pure-attention stacks. SSM/hybrid
         # recurrences integrate pad tokens into conv/state, so those archs
         # take the exact-shape path (see Model.prefill_bucketed).
-        attention_only = all(
-            kind == "attn" for g in model.groups for (kind, _) in g.sigs
+        self.bucketed_prefill = (
+            bucketed_prefill and _attention_only(model.cfg) and not legacy
         )
-        self.bucketed_prefill = bucketed_prefill and attention_only and not legacy
         if model.cfg.sliding_window and model.cfg.sliding_window < max_seq:
             # the slot pool is sized to max_seq but a sliding-window cache
             # rings at W=window: growing/splicing prefill caches into the
@@ -634,12 +664,12 @@ class ServingEngine:
         # token-packed prefill: admitted prompts concatenate into ONE
         # [1, pow2(total_tokens)] sequence with per-token segment ids, so
         # prefill cost tracks total TRUE tokens instead of rows x bucket.
-        # Same soundness gate as bucketing (attention-only), plus non-MLA:
-        # segment masking rides chunked_attention's plain-score path.
-        # Auto-downgrades silently (like bucketed_prefill) so cross-arch
-        # callers can set packed=True unconditionally.
-        self.packed = (
-            bool(packed) and self.bucketed_prefill and model.cfg.mla is None
+        # Derived when left unset (see resolve_packed); auto-downgrades
+        # silently (like bucketed_prefill) so cross-arch callers can set
+        # packed=True unconditionally.
+        self.packed = resolve_packed(
+            model.cfg, packed, bucketed_prefill=bucketed_prefill,
+            legacy=legacy, paged=self.paged, prefix_reuse=prefix_reuse,
         )
         # chunked prefill: prompts longer than prefill_chunk admit as a
         # sequence of fixed-width suffix-prefill chunks interleaved with
@@ -973,7 +1003,7 @@ class ServingEngine:
 
     def packed_grid(self) -> list:
         """Every pow2 packed width a packed admission can dispatch:
-        ``min_bucket .. pow2(max_batch * max_seq)``."""
+        ``min_bucket .. packed_cap()``."""
         out, T = [], min(self.min_bucket, self.packed_cap())
         while True:
             out.append(T)
@@ -1320,7 +1350,29 @@ class ServingEngine:
         self._t_mark = now  # prefill time is "preprocess", not "inference"
 
     def _prefill_packed(self, reqs: list, slots: list, jobs: list = None):
-        """One token-packed prefill for every admitted prompt.
+        """Token-packed prefill for every admitted prompt: one packed call,
+        or consecutive calls filled greedily in pick order where the
+        prompts together exceed :meth:`packed_cap`.
+
+        ``jobs`` is the paged admission's planned block rows; counters for
+        that path were already charged by :meth:`_admit_paged`.
+        """
+        cap = self.packed_cap()
+        start, total = 0, 0
+        for j, req in enumerate(reqs):
+            s = len(req.prompt_tokens)
+            if total + s > cap:
+                self._prefill_packed_call(
+                    reqs[start:j], slots[start:j],
+                    None if jobs is None else jobs[start:j],
+                )
+                start, total = j, 0
+            total += s
+        self._prefill_packed_call(reqs[start:], slots[start:],
+                                  None if jobs is None else jobs[start:])
+
+    def _prefill_packed_call(self, reqs: list, slots: list, jobs: list):
+        """One token-packed prefill call.
 
         Prompts concatenate back to back into a single [1, T] sequence
         (T = pow2 of the TOTAL true tokens, clamped to min_bucket), so a
@@ -1330,9 +1382,6 @@ class ServingEngine:
         unpacked run; the in-jit unpack emits the same bucketed-shaped
         artifact every downstream path (splice, disagg handoff, paged
         scatter) already consumes.
-
-        ``jobs`` is the paged admission's planned block rows; counters for
-        that path were already charged by :meth:`_admit_paged`.
         """
         n = len(reqs)
         npad = self.max_batch
@@ -1408,9 +1457,14 @@ class ServingEngine:
         self._t_mark = now
 
     def packed_cap(self) -> int:
-        """Widest packed sequence this engine can dispatch: every slot
-        admitted at once, each at a full max_seq prompt, rounded to pow2."""
-        return _next_pow2(self.max_batch * self.max_seq)
+        """Widest packed sequence this engine dispatches: pow2(max_seq),
+        the width one longest prompt needs. A packed row's attention is
+        dense over the whole row (other segments are masked, not
+        skipped), so its cost grows with the square of the width; held to
+        one prompt's width it stays within what a lone prompt's prefill
+        pays. (On one TPU v5e at granite-34b widths, 10 layers, an
+        8192-token row took 12.2 s against 0.34 s at 4096.)"""
+        return _next_pow2(self.max_seq)
 
     # ------------------------------------------------------------------ #
     # Chunked prefill: fixed-width chunks interleaved with decode steps

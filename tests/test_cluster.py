@@ -350,7 +350,7 @@ def test_affinity_reduces_prefill_compiles(model_bank):
     def compiles(policy):
         cl = ServingCluster.build(model, params, n_replicas=2,
                                   engine="fused", policy=policy,
-                                  max_batch=2, max_seq=128)
+                                  max_batch=2, max_seq=128, packed=False)
         _drain(cl, _requests(cfg, lens, max_new=2, seed=3),
                max_steps=100_000)
         return sum(r.engine.prefill_compile_count for r in cl.replicas)

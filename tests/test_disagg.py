@@ -270,6 +270,7 @@ def test_handoff_wire_bytes_equals_moved_payload(model_bank):
     for mode in TransferMode:
         eng = DisaggregatedEngine(
             model, params, transfer_mode=mode, max_batch=2, max_seq=64,
+            packed=False,
         )
         _drain(eng, cfg, lens, max_new=2)
         assert eng.handoffs == 2

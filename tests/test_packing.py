@@ -14,7 +14,11 @@ Covers, bottom-up:
 * the prefill-FLOPs proxy win (``prefill_padded_tokens``) on a ragged
   admission, and chunked prefill's decode interleaving;
 * warmup: packed/chunk jits pre-trace at construction (zero serve-time
-  compiles), and the knob-validation errors.
+  compiles), and the knob-validation errors;
+* the derived default (``packed`` left unset packs unless a paged pool
+  reuses prefixes, and the process-backend view derives it the same
+  way) and the packed-width cap, with admissions past it split into
+  consecutive packed calls.
 """
 
 import logging
@@ -28,6 +32,8 @@ import pytest
 from conftest import nodrop
 
 from repro.configs import ARCHITECTURES, get_config
+from repro.core import trace
+from repro.core.trace import Trace
 from repro.kernels import ops
 from repro.models.attention import chunked_attention
 from repro.serving import ServingEngine
@@ -54,6 +60,18 @@ def _drain(eng, reqs):
     out = eng.run_until_drained(max_steps=100_000)
     assert len(out) == len(reqs), (len(out), len(reqs))
     return [tuple(r.generated) for r in reqs]
+
+
+def _prefill_spans(eng, reqs):
+    """Drain ``reqs`` traced; return (tokens, the engine.prefill spans)."""
+    trace.enable_tracing(process="main")
+    try:
+        toks = _drain(eng, reqs)
+        spans = [s for s in Trace.from_buffer().spans
+                 if s.name == "engine.prefill"]
+    finally:
+        trace.disable_tracing()
+    return toks, spans
 
 
 def _pack(parts, pad_to=None):
@@ -226,7 +244,8 @@ _RAGGED = [5, 17, 33, 50]
 def test_packed_vs_bucketed_token_identity(engine_bank):
     cfg = get_config("llama3-8b").reduced()
     kw = dict(max_batch=4, max_seq=128, temperature=0.0)
-    base = _drain(engine_bank(cfg, **kw), _requests(cfg, _RAGGED))
+    base = _drain(engine_bank(cfg, packed=False, **kw),
+                  _requests(cfg, _RAGGED))
     eng = engine_bank(cfg, packed=True, **kw)
     assert eng.packed
     assert _drain(eng, _requests(cfg, _RAGGED)) == base
@@ -238,7 +257,7 @@ def test_packed_padded_token_win(engine_bank):
     bucketed path — while the true-token counters agree exactly."""
     cfg = get_config("llama3-8b").reduced()
     kw = dict(max_batch=4, max_seq=128, temperature=0.0)
-    bucketed = engine_bank(cfg, **kw)
+    bucketed = engine_bank(cfg, packed=False, **kw)
     packed = engine_bank(cfg, packed=True, **kw)
     _drain(bucketed, _requests(cfg, _RAGGED))
     _drain(packed, _requests(cfg, _RAGGED))
@@ -253,8 +272,9 @@ def test_packed_padded_token_win(engine_bank):
 def test_chunked_vs_bucketed_token_identity(engine_bank):
     cfg = get_config("llama3-8b").reduced()
     kw = dict(max_batch=4, max_seq=128, temperature=0.0)
-    base = _drain(engine_bank(cfg, **kw), _requests(cfg, _RAGGED))
-    eng = engine_bank(cfg, prefill_chunk=16, **kw)
+    base = _drain(engine_bank(cfg, packed=False, **kw),
+                  _requests(cfg, _RAGGED))
+    eng = engine_bank(cfg, packed=False, prefill_chunk=16, **kw)
     assert eng._chunk_enabled
     assert _drain(eng, _requests(cfg, _RAGGED)) == base
     # every chunk dispatches exactly chunk-width token rows
@@ -309,7 +329,8 @@ def test_packed_paged_token_identity(engine_bank):
     pages interleave segments, so they never align with the index)."""
     cfg = get_config("llama3-8b").reduced()
     kw = dict(max_batch=4, max_seq=128, temperature=0.0)
-    base = _drain(engine_bank(cfg, **kw), _requests(cfg, _RAGGED))
+    base = _drain(engine_bank(cfg, packed=False, **kw),
+                  _requests(cfg, _RAGGED))
     eng = engine_bank(cfg, paged=True, packed=True, **kw)
     assert eng.packed and eng.paged and not eng.prefix_reuse
     assert _drain(eng, _requests(cfg, _RAGGED)) == base
@@ -322,9 +343,9 @@ def test_packed_disagg_token_identity(model_bank):
     cfg = get_config("llama3-8b").reduced()
     model, params = model_bank(cfg)
     kw = dict(max_batch=4, max_seq=128, temperature=0.0)
-    base = _drain(ServingEngine(model, params, **kw),
+    base = _drain(ServingEngine(model, params, packed=False, **kw),
                   _requests(cfg, _RAGGED))
-    for dkw in (dict(packed=True), dict(prefill_chunk=16)):
+    for dkw in (dict(packed=True), dict(packed=False, prefill_chunk=16)):
         eng = DisaggregatedEngine(
             model, params, transfer_mode=TransferMode.DIRECT_HBM, **kw,
             **dkw,
@@ -348,7 +369,7 @@ def test_cross_arch_packed_chunked_identity(name, engine_bank):
     cfg = nodrop(ARCHITECTURES[name].reduced())
     kw = dict(max_batch=2, max_seq=128, temperature=0.0)
     lens = [9, 40]
-    base = _drain(engine_bank(cfg, **kw), _requests(cfg, lens))
+    base = _drain(engine_bank(cfg, packed=False, **kw), _requests(cfg, lens))
     eng = engine_bank(cfg, packed=True, prefill_chunk=32, **kw)
     assert eng.packed and eng._chunk_enabled
     assert _drain(eng, _requests(cfg, lens)) == base
@@ -421,11 +442,96 @@ def test_warmup_packed_chunk_zero_compiles(model_bank):
 
     warm = ServingEngine(model, params, warmup=True, **kw)
     assert warm.warm_s > 0
-    # packed grid covers min_bucket .. pow2(max_batch * max_seq)
-    assert warm.packed_grid() == [16, 32, 64, 128]
+    # packed grid covers min_bucket .. pow2(max_seq)
+    assert warm.packed_grid() == [16, 32, 64]
     shapes = warm.prefill_compile_count
     compiles = _compiles_during(
         lambda: _drain(warm, _requests(cfg, [5, 40]))
     )
     assert compiles == [], f"compiled inside the serving window: {compiles}"
     assert warm.prefill_compile_count == shapes
+
+
+# --------------------------------------------------------------------------- #
+# The derived default and the packed-width cap
+# --------------------------------------------------------------------------- #
+def test_default_packs_attention_only_ring_pool(model_bank):
+    """Left unset, ``packed`` is derived: an attention-only ring-pool
+    engine packs, and every admission's prefill span says so."""
+    cfg = get_config("llama3-8b").reduced()
+    model, params = model_bank(cfg)
+    eng = ServingEngine(model, params, max_batch=4, max_seq=128)
+    assert eng.packed
+    _toks, spans = _prefill_spans(eng, _requests(cfg, _RAGGED))
+    assert spans and all(s.attrs["path"] == "packed" for s in spans)
+
+
+def test_default_paged_with_reuse_keeps_bucket_path(model_bank):
+    """A paged pool that reuses prefixes keeps reuse and the bucket path
+    by default; the same pool without reuse packs."""
+    cfg = get_config("llama3-8b").reduced()
+    model, params = model_bank(cfg)
+    eng = ServingEngine(model, params, max_batch=4, max_seq=128, paged=True)
+    assert eng.prefix_reuse and not eng.packed
+    _toks, spans = _prefill_spans(eng, _requests(cfg, _RAGGED))
+    assert spans and all(s.attrs["path"] == "paged" for s in spans)
+    no_reuse = ServingEngine(model, params, max_batch=4, max_seq=128,
+                             paged=True, prefix_reuse=False)
+    assert no_reuse.packed and not no_reuse.prefix_reuse
+
+
+@pytest.mark.parametrize("max_batch,max_seq,cap", [
+    (4, 2112, 4096),  # the granite-completion deployment
+    (2, 64, 64),
+    (2, 48, 64),
+    (1, 48, 64),
+    (8, 64, 64),      # the slot count does not widen the row
+])
+def test_packed_cap_is_one_prompt_width(model_bank, max_batch, max_seq, cap):
+    """The packed row is capped at pow2(max_seq): one longest prompt
+    always fits, and no row is wider."""
+    cfg = get_config("llama3-8b").reduced()
+    model, params = model_bank(cfg)
+    eng = ServingEngine(model, params, max_batch=max_batch, max_seq=max_seq,
+                        min_bucket=16)
+    assert eng.packed_cap() == cap
+    assert eng.packed_grid() == [w for w in (16, 32, 64, 128, 256, 512,
+                                             1024, 2048, 4096) if w <= cap]
+
+
+def test_admission_past_cap_splits_into_packed_calls(model_bank):
+    """With a non-pow2 max_seq the cap (64 here) sits below the sum of an
+    admission's prompts (40 + 30): the admission goes out as two packed
+    calls in pick order, token for token what the bucket path serves."""
+    cfg = get_config("llama3-8b").reduced()
+    model, params = model_bank(cfg)
+    kw = dict(max_batch=2, max_seq=48, temperature=0.0)
+    base = _drain(ServingEngine(model, params, packed=False, **kw),
+                  _requests(cfg, [40, 30]))
+    eng = ServingEngine(model, params, **kw)
+    assert eng.packed and eng.packed_cap() == 64
+    toks, spans = _prefill_spans(eng, _requests(cfg, [40, 30]))
+    assert toks == base
+    assert [(s.attrs["path"], s.attrs["bucket"], s.attrs["rows"])
+            for s in spans] == [("packed", 64, 1), ("packed", 32, 1)]
+    assert eng.prefill_padded_tokens == 64 + 32
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("llama3-8b", {}),
+    ("llama3-8b", {"packed": False}),
+    ("llama3-8b", {"paged": True}),
+    ("llama3-8b", {"paged": True, "prefix_reuse": False}),
+    ("llama3-8b", {"legacy": True, "packed": True}),
+    ("mamba2-130m", {}),
+])
+def test_process_view_derives_packed_like_the_engine(model_bank, name, kw):
+    """The process backend's engine view derives ``packed`` from the same
+    options, so the router keys requests on the worker's real path."""
+    from repro.serving.cluster import _RemoteEngineFacade
+
+    cfg = nodrop(ARCHITECTURES[name].reduced())
+    model, params = model_bank(cfg)
+    eng = ServingEngine(model, params, max_batch=2, max_seq=64, **kw)
+    view = _RemoteEngineFacade(None, {"cfg": cfg, "engine_kw": kw})
+    assert view.packed == eng.packed

@@ -304,10 +304,12 @@ def test_warmup_fused_engine_and_bucket_grid(model_bank):
     min_bucket..max_seq."""
     cfg = get_config("llama3-8b").reduced()
     model, params = model_bank(cfg, dtype=jnp.float32, seed=1)
-    eng = ServingEngine(model, params, max_batch=2, max_seq=64, warmup=True)
+    eng = ServingEngine(model, params, max_batch=2, max_seq=64, warmup=True,
+                        packed=False)
     assert eng.bucket_grid() == [16, 32, 64]
     assert eng.prefill_compile_count == 3
-    base, _ = _drain(ServingEngine(model, params, max_batch=2, max_seq=64),
+    base, _ = _drain(ServingEngine(model, params, max_batch=2, max_seq=64,
+                                   packed=False),
                      cfg, [5, 40])
     out, _ = _drain(eng, cfg, [5, 40])
     assert [r.generated for r in out] == [r.generated for r in base]

@@ -68,7 +68,8 @@ def test_bucketed_prefill_compile_count(model_bank):
     cfg = get_config("llama3-8b").reduced()
     model, params = model_bank(cfg)
     max_seq = 256
-    eng = ServingEngine(model, params, max_batch=1, max_seq=max_seq)
+    eng = ServingEngine(model, params, max_batch=1, max_seq=max_seq,
+                        packed=False)
     lens = list(range(5, 245, 12))  # 20 distinct prompt lengths
     assert len(set(lens)) == 20
     for req in _requests(cfg, lens, max_new=2):

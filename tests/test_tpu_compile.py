@@ -1,15 +1,19 @@
 """Ahead-of-time compiles for a described TPU v5e (2x2), with no chip.
 
 The Pallas kernels and the full-width decode step are compiled by the
-TPU compiler at starcoder2-3b widths: what Mosaic would refuse (a block
-not aligned to the tiling, too much VMEM) or a step that does not fit
-one chip's 16 GiB fails here, before any chip time is spent. Nothing
-runs, so these tests say nothing about results or times.
+TPU compiler at starcoder2-3b widths, and the widest packed prefill of
+the granite-completion deployment at granite-34b-10l widths: what Mosaic
+would refuse (a block not aligned to the tiling, too much VMEM) or a
+program that does not fit one chip's 16 GiB fails here, before any chip
+time is spent. Nothing runs, so these tests say nothing about results or
+times.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models import Model
+from repro.serving import ServingEngine
 
 GIB = 1 << 30
 CHIP_HBM = 16 * GIB
@@ -116,4 +121,29 @@ def test_full_width_decode_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes > 7 * GIB  # the whole model is there
+    assert used < CHIP_HBM, f"{used / GIB:.2f} GiB does not fit one chip"
+
+
+def test_widest_packed_prefill_fits_one_chip(one_chip):
+    """granite-34b at its published widths, 10 layers, served with
+    max_batch 4 and max_seq 2112: the packed prefill at the engine's cap
+    (a 4096-token row, unpacked to the pool's rows in the same program)
+    fits one chip beside the stage's weights and the decode pool."""
+    model = Model(dataclasses.replace(get_config("granite-34b"), n_layers=10))
+    eng = ServingEngine(model, None, max_batch=4, max_seq=2112,
+                        min_bucket=128)
+    T = eng.packed_cap()
+    assert eng.packed and T == 4096
+    place = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(place, model.param_specs())
+    row = _spec(one_chip, (1, T), jnp.int32)
+    per_slot = _spec(one_chip, (eng.max_batch,), jnp.int32)
+    compiled = jax.jit(eng._prefill_packed_impl).lower(
+        params, row, row, row, per_slot, per_slot, place(eng.prefill_key)
+    ).compile()
+    mem = compiled.memory_analysis()
+    pool = sum(x.nbytes for x in jax.tree.leaves(eng.pool.caches))
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + pool)
+    assert mem.argument_size_in_bytes > 11 * 10**9  # the stage's weights
     assert used < CHIP_HBM, f"{used / GIB:.2f} GiB does not fit one chip"

@@ -195,11 +195,11 @@ def test_validate_stamps():
 # --------------------------------------------------------------------------- #
 # real engine drain: reconciliation, determinism, export, debug stamps
 # --------------------------------------------------------------------------- #
-def _traced_drain(model_bank, seed=0, debug_stamps=False):
+def _traced_drain(model_bank, seed=0, debug_stamps=False, **engine_kw):
     cfg = get_config("llama3-8b").reduced()
     model, params = model_bank(cfg)
     eng = ServingEngine(model, params, max_batch=2, max_seq=64,
-                        warmup=True, debug_stamps=debug_stamps)
+                        warmup=True, debug_stamps=debug_stamps, **engine_kw)
     trace.enable_tracing(process="main")
     for req in _requests(cfg, [5, 11, 23, 37], seed=seed):
         eng.submit(req, time.perf_counter())
@@ -230,8 +230,10 @@ def test_engine_drain_reconciles_and_trees_are_wellformed(model_bank):
 
 def test_engine_phase_spans_nest_in_each_step(model_bank):
     """Every engine.* phase of a drain sits inside an engine.step span,
-    with the attrs the benchmark's readers use."""
-    eng, _out, tr = _traced_drain(model_bank)
+    with the attrs the benchmark's readers use. On the bucket path the
+    four prompts split over several calls, so a later one finds live
+    decode rows."""
+    eng, _out, tr = _traced_drain(model_bank, packed=False)
     eng_spans = [s for s in tr.spans if s.name.startswith("engine.")]
     by_name: dict = {}
     for s in eng_spans:
