@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from . import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
 

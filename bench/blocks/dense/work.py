@@ -8,10 +8,6 @@ read whole as the output head (the program ties the two).
 
 from __future__ import annotations
 
-import json
-from pathlib import Path
-
-PEAKS = Path(__file__).resolve().parent / "peaks.json"
 BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
@@ -76,12 +72,3 @@ def decode_work(cfg: dict, steps: int, contexts) -> tuple:
     nbytes = steps * weight_bytes(cfg) + sum(kv_bytes(cfg, c) for c in contexts)
     return flops, nbytes
 
-
-def peaks(device_kind: str, path: Path = PEAKS) -> dict:
-    """Peak rates of one chip of ``device_kind``; an unknown kind raises."""
-    with open(path) as f:
-        table = json.load(f)["devices"]
-    if device_kind not in table:
-        raise KeyError(f"no peaks for device kind {device_kind!r} in "
-                       f"{path.name} (have {sorted(table)})")
-    return table[device_kind]

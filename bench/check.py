@@ -2,7 +2,8 @@
 
 After the window closes and the program's state is freed, a sample of
 the finished requests, drawn from the run's seed and always holding the
-longest, goes through the float32 reference once: each prompt followed
+longest, goes through the float32 reference of the configuration's
+block once: each prompt followed
 by the tokens that were served for it. Every served token is greedy, so
 its reference logit should be the best at its position, up to the
 rounding of the served type. The number compared is the widest gap by
@@ -16,8 +17,6 @@ chips), so one gap covers all three.
 from __future__ import annotations
 
 import numpy as np
-
-from bench import reference
 
 SAMPLE_TOKENS = 320  # served tokens the sample reaches at least
 SAMPLE_MIN, SAMPLE_MAX = 4, 12  # requests in the sample
@@ -43,9 +42,11 @@ def sample(finished: list, seed: int) -> list:
     return out
 
 
-def gaps(cfg: dict, seed: int, picked: list, control: bool = False) -> dict:
+def gaps(block, cfg: dict, seed: int, picked: list,
+         control: bool = False) -> dict:
     """Widest gap of the served tokens (and, with ``control``, of the
-    tokens the float8 control ranks first) below the reference's best."""
+    tokens the float8 control ranks first) below the best of ``block``'s
+    reference."""
     seqs, positions, served = [], [], []
     for s in picked:
         prompt = np.asarray(s.req.prompt_tokens, np.int32)
@@ -54,7 +55,7 @@ def gaps(cfg: dict, seed: int, picked: list, control: bool = False) -> dict:
         positions.append(len(prompt) - 1 + np.arange(len(gen)))
         served.append(gen)
     precisions = ("f32", "fp8") if control else ("f32",)
-    lg = reference.logits(cfg, seed, seqs, positions, precisions)
+    lg = block.reference.logits(cfg, seed, seqs, positions, precisions)
     ref = lg["f32"]
     best = [r.max(-1) for r in ref]
     out = {

@@ -6,13 +6,17 @@ planes: ``engine.step`` over each ``step()``, ``engine.admit`` (stat
 ``admitted``: requests it placed), ``engine.prefill`` (``path``,
 ``bucket``, ``rows``, ``live_rows``: decode rows live when it was
 dispatched), ``engine.dispatch`` (``steps``), ``engine.harvest``
-(``live``) and ``engine.handoff``.
+(``live``) and ``engine.handoff`` (``mechanism``, ``wire_bytes``,
+``requests``, ``charge``: "measured" where the span's wall is what the
+request is charged, "modeled" where the charge comes from constants).
 
 ``load`` reads them with their stats, and the device planes as
 ``trace_reduce.load`` does. ``reduce`` gives, for the traced window:
 
 * ``spans``: per engine span name, its count and seconds;
 * ``admit_block_s``: the wall of each admission that placed a request;
+* ``handoffs``: per ``engine.handoff`` span, its wall (``wall_s``) and
+  its ``wire_bytes``, ``mechanism`` and ``charge`` stats;
 * ``step_idle_s``: per chip that decodes, the seconds inside
   ``engine.step`` spans between the chip's programs (the host had work
   and the chip waited for its next program); the bubbles between a
@@ -129,6 +133,9 @@ def reduce(tr: dict, patterns: dict) -> dict:
         tot["seconds"] += (e - s) / 1e9
     admits = [(e - s) / 1e9 for name, s, e, st in engine
               if name == "engine.admit" and st.get("admitted", 0) >= 1]
+    handoffs = [{"wall_s": (e - s) / 1e9, "wire_bytes": st.get("wire_bytes"),
+                 "mechanism": st.get("mechanism"), "charge": st.get("charge")}
+                for name, s, e, st in engine if name == "engine.handoff"]
     steps = trace_reduce.merge(trace_reduce.clip(
         [(s, e) for name, s, e, _ in engine if name == "engine.step"], lo, hi))
     prefills = [(s, e, st.get("live_rows", 0)) for name, s, e, st in engine
@@ -180,7 +187,7 @@ def reduce(tr: dict, patterns: dict) -> dict:
         labelled.append([f"{label}/{name}" if name else label,
                          (g[1] - g[0]) / 1e9])
     return {"window_s": window_s, "spans": spans, "admit_block_s": admits,
-            "step_idle_s": step_idle, "prefill_stall_s": stall,
+            "handoffs": handoffs, "step_idle_s": step_idle, "prefill_stall_s": stall,
             "idle_gaps": labelled}
 
 

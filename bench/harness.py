@@ -17,7 +17,6 @@ its stage records by the readers in ``metrics/``.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import gc
 import math
 import os
@@ -29,11 +28,7 @@ from types import SimpleNamespace
 
 import numpy as np
 
-from bench import check, client, loadgen, manifest, trace_reduce, work
-from bench import weights as W
-
-SHAPE_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "rope_theta", "norm_eps")
+from bench import check, client, loadgen, manifest, peaks, trace_reduce
 
 
 class NoAccelerator(RuntimeError):
@@ -66,27 +61,25 @@ def use_cache(root: Path):
     return use_compile_cache()
 
 
-def model_config(cfg: dict):
-    """The program's config for the cell's sizes; only the dense block
-    that ``reference.py`` mirrors is accepted."""
+def model_config(cfg: dict, block):
+    """The program's config for the cell's sizes, as the configuration's
+    block sets them; one the block's reference does not cover is
+    refused."""
     from repro.configs import get_config
 
-    base = get_config(cfg["repro_config"])
-    mc = dataclasses.replace(base, name=cfg["name"],
-                             **{k: cfg[k] for k in SHAPE_KEYS})
-    if (mc.family != "dense" or mc.qk_norm or mc.sliding_window
-            or mc.moe or mc.mla or mc.ssm):
-        raise ValueError(f"{cfg['name']}: the reference covers the plain "
-                         "dense block only")
+    mc = block.configure(get_config(cfg["repro_config"]), cfg)
+    if not block.accepts(mc):
+        raise ValueError(f"{cfg['name']}: the {cfg['family']!r} block's "
+                         "reference does not cover this configuration")
     return mc
 
 
-def check_tree(model, cfg: dict):
+def check_tree(model, cfg: dict, block):
     """The generated tree has the program's layout, leaf for leaf."""
     import jax
 
     want = model.param_specs()
-    got = W.abstract_tree(cfg)
+    got = block.weights.abstract_tree(cfg)
     if jax.tree.structure(want) != jax.tree.structure(got):
         raise ValueError("generated weights do not match the program's "
                          f"parameter tree:\n{jax.tree.structure(want)}\n"
@@ -250,21 +243,22 @@ def end_to_end(cl, t_end: float, setup_s: float) -> dict:
     return out
 
 
-def layer_context(cfg, cl, recs, c0, c1, reduced, kind) -> SimpleNamespace:
-    """What the metric readers read: the window's requests as the client
-    saw them, the program's stage records and counter deltas, the trace
-    reduction, and the chip's peaks."""
+def layer_context(cfg, block, cl, recs, c0, c1, reduced,
+                  kind) -> SimpleNamespace:
+    """What the metric readers read: the configuration and its block, the
+    window's requests as the client saw them, the program's stage records
+    and counter deltas, the trace reduction, and the chip's peaks."""
     seen = [s for s in cl.seen if s.req is not None]
     prefilled = [len(s.req.prompt_tokens) for s in seen if s.first is not None]
     contexts = [len(s.req.prompt_tokens) + j for s in seen
                 for j in range(1, s.n)]
     return SimpleNamespace(
-        cfg=cfg,
+        cfg=cfg, block=block,
         records={s.req.request_id: recs[s.req.request_id] for s in seen
                  if s.req.request_id in recs},
         counters={k: c1[k] - c0[k] for k in COUNTERS},
         trace=reduced, prefilled=prefilled, decode_contexts=contexts,
-        peaks=work.peaks(kind) if kind else None,
+        peaks=peaks.peaks(kind) if kind else None,
     )
 
 
@@ -290,11 +284,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     if loadgen.longest(mix) > max_seq:
         raise ValueError(f"{name}: a request of {loadgen.longest(mix)} tokens "
                          f"does not fit max_seq {max_seq}")
-    model = Model(model_config(cfg))
-    check_tree(model, cfg)
+    block = manifest.load_block(cfg, bench_dir)
+    model = Model(model_config(cfg, block))
+    check_tree(model, cfg, block)
     # uncommitted, like the program's own init: the engines' warm-up then
     # compiles for the same argument placement the window uses
-    params = jax.block_until_ready(W.make_params(cfg, seed))
+    params = jax.block_until_ready(block.weights.make_params(cfg, seed))
     front, engines, used = build(dep, model, params)
     del params
     schedule = loadgen.schedule(mix, seed, seconds, cfg["vocab_size"])
@@ -327,7 +322,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     breakdown, dev_extra = None, {}
     if trace:
         reduced = trace_reduce.reduce_dir(trace_dir, bench_dir / "layers.json")
-        ctx = layer_context(cfg, cl, recs, c0, c1, reduced,
+        ctx = layer_context(cfg, block, cl, recs, c0, c1, reduced,
                             kind if accelerator else None)
         metrics = {}
         for m in cell.per_layer:
@@ -346,7 +341,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
 
     # the control's tokens take the served tokens' place in the comparison
     picked = check.sample(finished, seed)
-    got = check.gaps(cfg, seed, picked, control) if picked else None
+    got = check.gaps(block, cfg, seed, picked, control) if picked else None
     gap = got["control_gap" if control else "served_gap"] if got else math.inf
     limit = dep["check"]
     checks = {

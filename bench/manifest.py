@@ -9,17 +9,34 @@ Its files are:
   replicas, ``max_batch``, ``max_seq``, ``min_bucket``, ``handoff_block``,
   transfer mode);
 * ``traffic/<traffic>.json``: the mix the load generator reads;
-* ``metrics/<metric>.py`` for every per-layer metric the cell reports.
+* ``metrics/<metric>.py`` for every per-layer metric the cell reports;
+* ``blocks/<family>/``, the package of the configuration file's
+  ``family``: what the benchmark knows of that model block.
+
+A block package holds ``weights`` (``make_params(cfg, seed)``, the served
+tree made from the seed on the device in one jitted call, and
+``abstract_tree(cfg)``, its shapes), ``reference`` (``logits(cfg, seed,
+seqs, positions, precisions)``, the plain float32 forward and its
+lower-precision control), ``work`` (``prefill_flops``, ``decode_flops``,
+``decode_work``: useful operations and bytes from sizes and true
+lengths), and two functions: ``configure(base, cfg)`` gives the program's
+config at the file's sizes, and ``accepts(mc)`` says whether the
+reference covers it. The harness, the check and the readers reach the
+block only through :func:`load_block`.
 
 A later cell, mix or metric is new files plus new entries: nothing here
-names a cell.
+names a cell. A configuration of another block is a configuration file
+whose ``family`` names a new ``blocks/<family>/`` package, plus that
+package.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent  # the checkout
@@ -79,3 +96,35 @@ def load_reader(metric: str, bench_dir: Path = BENCH):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def families(bench_dir: Path = BENCH) -> list:
+    """The block families ``blocks/`` holds."""
+    return sorted(p.parent.name
+                  for p in (Path(bench_dir) / "blocks").glob("*/__init__.py"))
+
+
+def load_block(cfg: dict, bench_dir: Path = BENCH):
+    """The package ``blocks/<family>/`` for configuration ``cfg``, by its
+    ``family`` key; an unknown family raises and names those there are."""
+    family = cfg["family"]
+    have = families(bench_dir)
+    if family not in have:
+        raise KeyError(f"{cfg.get('name', '?')}: no block for family "
+                       f"{family!r} in {Path(bench_dir) / 'blocks'} "
+                       f"(have {have})")
+    path = (Path(bench_dir) / "blocks" / family).resolve()
+    # one module per directory, so a block's jitted functions are built once
+    tag = hashlib.sha1(str(path).encode()).hexdigest()[:10]
+    name = f"bench_block_{family.replace('.', '_').replace('-', '_')}_{tag}"
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, path / "__init__.py", submodule_search_locations=[str(path)])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]

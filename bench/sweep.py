@@ -66,15 +66,16 @@ def main(argv=None) -> int:
     import jax
 
     from bench import harness, loadgen, manifest
-    from bench import weights as W
     from repro.models import Model
 
     cell = manifest.load_cell(args.workload)
     harness.require_chips(cell.chips)
     harness.use_cache(manifest.ROOT)
     cfg = cell.config
-    model = Model(harness.model_config(cfg))
-    params = jax.block_until_ready(W.make_params(cfg, args.seeds[0]))
+    block = manifest.load_block(cfg)
+    model = Model(harness.model_config(cfg, block))
+    params = jax.block_until_ready(block.weights.make_params(cfg,
+                                                             args.seeds[0]))
     front, _engines, _ = harness.build(cell.deployment, model, params)
     del params
     print(f"setup {time.perf_counter() - T_START:.1f} s", file=sys.stderr)

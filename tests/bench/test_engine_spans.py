@@ -1,4 +1,4 @@
-"""The program's engine spans: their reduction and the three readers."""
+"""The program's engine spans: their reduction and the readers of them."""
 
 from __future__ import annotations
 
@@ -12,14 +12,14 @@ import pytest
 
 from bench import engine_spans, harness, manifest, trace_reduce
 
-from tinycell import write_tiny_root
+from tinycell import DEPLOYMENTS, write_tiny_root
 
 PATTERNS = json.loads((manifest.BENCH / "layers.json").read_text())["layers"]
 MS = 1_000_000  # ns
 FIXTURE = manifest.ROOT / "tests" / "bench" / "fixtures" / \
     "granite-completion-0.5s.xplane.pb.gz"
 READERS = ("admit_block_p95_ms", "host_stall_share",
-           "prefill_stall_ms_per_token")
+           "prefill_stall_ms_per_token", "kv_handoff_ms")
 
 
 def _synthetic():
@@ -136,11 +136,48 @@ def test_readers_on_the_synthetic_trace(traced_root, monkeypatch):
     got = {m: manifest.load_reader(m, root / "bench")(ctx) for m in READERS}
     assert got == {"admit_block_p95_ms": pytest.approx(43.0),
                    "host_stall_share": pytest.approx(19.0),
-                   "prefill_stall_ms_per_token": pytest.approx(5.0)}
+                   "prefill_stall_ms_per_token": pytest.approx(5.0),
+                   "kv_handoff_ms": None}
     # the breakdown the harness prints now names the in-step gaps
     assert [g[0] for g in reduced["idle_gaps"]] == [
         "bench.idle", "bench.step/engine.admit", "bench.step/engine.step",
         "bench.step/engine.prefill", "bench.step/engine.prefill"]
+
+
+def _handoffs(*charges):
+    """The synthetic trace with one ``engine.handoff`` span per charge,
+    of 2, 3 and 8 ms."""
+    tr = _synthetic()
+    for i, charge in enumerate(charges):
+        s = (72 + 4 * i) * MS
+        tr["engine"].append(("engine.handoff", s, s + (2 + i + 4 * (i > 1))
+                             * MS, {"mechanism": "DIRECT_HBM",
+                                    "wire_bytes": 1 << 20, "requests": 1,
+                                    "charge": charge}))
+    return tr
+
+
+@pytest.mark.parametrize("charges,want", [
+    (("measured", "measured"), 2.5),
+    (("measured", "measured", "measured"), 3.0),
+    (("measured", "modeled"), None),
+    ((), None),
+])
+def test_kv_handoff_ms_on_a_synthetic_trace(traced_root, monkeypatch,
+                                            charges, want):
+    """The median wall of the window's handoff spans; nothing where any
+    span's charge is modeled, or where no handoff ran."""
+    root = traced_root(b"")
+    tr = _handoffs(*charges)
+    monkeypatch.setattr(engine_spans, "load", lambda path: tr)
+    engine_spans._CACHE.clear()
+    sec = engine_spans.reduce(tr, PATTERNS)
+    assert [h["charge"] for h in sec["handoffs"]] == list(charges)
+    assert all(h["wire_bytes"] == 1 << 20 and h["mechanism"] == "DIRECT_HBM"
+               for h in sec["handoffs"])
+    ctx = _ctx(trace_reduce.reduce(tr, PATTERNS))
+    got = manifest.load_reader("kv_handoff_ms", root / "bench")(ctx)
+    assert got == (None if want is None else pytest.approx(want))
 
 
 def test_chip_trace_without_engine_spans(traced_root):
@@ -182,8 +219,11 @@ def test_tiny_traced_run_reads_engine_spans(tmp_path):
     assert {"engine.step", "engine.admit", "engine.prefill",
             "engine.dispatch", "engine.harvest"} <= set(stats)
     assert all(st["admitted"] >= 0 for st in stats["engine.admit"])
+    # the fused engine packs each admission's prompts into one token row
+    max_batch = DEPLOYMENTS["tiny-fused"]["engine_options"]["max_batch"]
     for st in stats["engine.prefill"]:
-        assert st["path"] == "bucket" and st["rows"] >= 1
-        assert {"bucket", "live_rows"} <= set(st)
+        assert st["path"] == "packed" and "bucket" in st
+        assert 1 <= st["rows"] <= max_batch
+        assert 0 <= st["live_rows"] <= max_batch
     assert all("steps" in st for st in stats["engine.dispatch"])
     assert all("live" in st for st in stats["engine.harvest"])

@@ -105,14 +105,15 @@ def test_no_modeled_stage_reaches_the_metrics(tiny_root):
     latencies do."""
     import jax
 
-    from bench import manifest, weights
+    from bench import manifest
     from repro.core.transport import Transport
     from repro.models import Model
     from repro.serving import ServingEngine
 
     cell = manifest.load_cell("tiny-fused", tiny_root, tiny_root / "bench")
-    model = Model(harness.model_config(cell.config))
-    params = weights.make_params(cell.config, 4)
+    block = manifest.load_block(cell.config, tiny_root / "bench")
+    model = Model(harness.model_config(cell.config, block))
+    params = block.weights.make_params(cell.config, 4)
     dep = cell.deployment
     eng = ServingEngine(model, params, **harness.engine_options(dep),
                         transport=Transport.TCP, profile=_Modeled())
@@ -136,13 +137,14 @@ def test_window_stamps_on_the_harness_clock(tiny_root):
     after it was due, and tokens never outrun the budget."""
     import jax
 
-    from bench import manifest, weights
+    from bench import manifest
     from repro.models import Model
     from repro.serving import ServingEngine
 
     cell = manifest.load_cell("tiny-fused", tiny_root, tiny_root / "bench")
-    model = Model(harness.model_config(cell.config))
-    params = jax.block_until_ready(weights.make_params(cell.config, 5))
+    block = manifest.load_block(cell.config, tiny_root / "bench")
+    model = Model(harness.model_config(cell.config, block))
+    params = jax.block_until_ready(block.weights.make_params(cell.config, 5))
     dep = cell.deployment
     eng = ServingEngine(model, params, **harness.engine_options(dep))
     sched = loadgen.schedule(cell.traffic, 6, 2.0, cell.config["vocab_size"])
@@ -161,7 +163,7 @@ def test_closed_loop_keeps_its_clients_busy(tiny_root):
     never more than two are open at once."""
     import jax
 
-    from bench import manifest, weights
+    from bench import manifest
     from repro.models import Model
 
     cell = manifest.load_cell("tiny-fused", tiny_root, tiny_root / "bench")
@@ -170,8 +172,9 @@ def test_closed_loop_keeps_its_clients_busy(tiny_root):
     assert loadgen.clients(mix) == 2
     sched = loadgen.schedule(mix, 8, 2.0, cell.config["vocab_size"])
     assert len(sched) == 800 and all(a.t == 0.0 for a in sched)
-    model = Model(harness.model_config(cell.config))
-    params = jax.block_until_ready(weights.make_params(cell.config, 5))
+    block = manifest.load_block(cell.config, tiny_root / "bench")
+    model = Model(harness.model_config(cell.config, block))
+    params = jax.block_until_ready(block.weights.make_params(cell.config, 5))
     front, _, _ = harness.build(cell.deployment, model, params)
     cl, t0, t_end, failed = harness.serve_window(front, sched, 2.0,
                                                  harness._null, clients=2)

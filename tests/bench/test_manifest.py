@@ -138,3 +138,110 @@ def test_unknown_deployment_key_is_refused():
                       f"{CELLS[0]}.json").read_text())
     with pytest.raises(ValueError, match="unknown deployment keys"):
         harness.engine_options(dict(dep, packed=True))
+
+
+STUB_BLOCK = '''"""A block of another family for the test: the dense block's
+parts, under a gate of its own that refuses more than two layers."""
+
+import dataclasses
+
+from . import reference, weights, work  # noqa: F401
+
+KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+        "vocab_size", "rope_theta", "norm_eps")
+
+
+def configure(base, cfg):
+    return dataclasses.replace(base, name=cfg["name"],
+                               **{k: cfg[k] for k in KEYS})
+
+
+def accepts(mc):
+    return mc.n_layers <= 2
+'''
+
+
+def _stub_tree(root):
+    """The tiny benchmark tree, plus a ``stub`` block (new files only) and
+    configurations of that family; returns the bytes of every file the
+    tree held before."""
+    from tinycell import TINY_CONFIG, write_tiny_root
+
+    write_tiny_root(root)
+    bench = root / "bench"
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+    stub = bench / "blocks" / "stub"
+    stub.mkdir()
+    (stub / "__init__.py").write_text(STUB_BLOCK)
+    for part in ("weights", "reference", "work"):
+        shutil.copy(bench / "blocks" / "dense" / f"{part}.py", stub)
+    for name, layers in (("tiny-stub", 2), ("deep-stub", 3)):
+        (bench / "configs" / f"{name}.json").write_text(json.dumps(
+            dict(TINY_CONFIG, name=name, family="stub", n_layers=layers)))
+    return before
+
+
+def test_a_block_is_found_by_its_family(tmp_path):
+    """A configuration whose ``family`` names a new block package loads
+    that package, and the package's own ``accepts`` is what gates it."""
+    before = _stub_tree(tmp_path)
+    bench = tmp_path / "bench"
+    assert manifest.families(bench) == ["dense", "stub"]
+    cfgs = {n: json.loads((bench / "configs" / f"{n}.json").read_text())
+            for n in ("tiny-stub", "deep-stub")}
+    block = manifest.load_block(cfgs["tiny-stub"], bench)
+    assert block.accepts.__module__ == block.__name__
+    assert manifest.load_block(cfgs["deep-stub"], bench) is block
+    assert harness.model_config(cfgs["tiny-stub"], block).n_layers == 2
+    # the dense block would take three layers; the stub's gate refuses them
+    dense = manifest.load_block(dict(cfgs["deep-stub"], family="dense"),
+                                bench)
+    assert harness.model_config(cfgs["deep-stub"], dense).n_layers == 3
+    with pytest.raises(ValueError, match="'stub' block's reference"):
+        harness.model_config(cfgs["deep-stub"], block)
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_unknown_family_raises_and_names_the_families():
+    with pytest.raises(KeyError, match=r"'moe'.*\['dense'\]"):
+        manifest.load_block({"name": "x", "family": "moe"})
+
+
+def test_dense_block_refuses_what_its_reference_does_not_cover():
+    """The dense block's gate: a config with experts, latent attention,
+    state-space layers, QK norm or a window is refused."""
+    import dataclasses
+
+    cfg = json.loads((manifest.BENCH / "configs" /
+                      "starcoder2-3b.json").read_text())
+    block = manifest.load_block(cfg)
+    mc = harness.model_config(cfg, block)
+    assert block.accepts(mc)
+    for change in ({"qk_norm": True}, {"sliding_window": 4096},
+                   {"family": "moe"}):
+        assert not block.accepts(dataclasses.replace(mc, **change))
+
+
+def test_another_family_runs_with_new_files_only(tmp_path):
+    """A cell on a configuration of the stub family runs a whole window on
+    the CPU, correct, with nothing but the new block, its configuration
+    file and the cell's entries added to the tree."""
+    import time
+
+    before = _stub_tree(tmp_path)
+    man = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    man["configs"].append({"name": "tiny-stub", "source": "test",
+                           "file": "bench/configs/tiny-stub.json",
+                           "reduced": [], "why": "CPU test"})
+    man["workloads"].append({"name": "stub-fused", "config": "tiny-stub",
+                             "traffic": "tiny-mix", "chips": 1,
+                             "why": "CPU test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
+    (tmp_path / "bench" / "deployments" / "stub-fused.json").write_text(
+        (tmp_path / "bench" / "deployments" / "tiny-fused.json").read_text())
+    out = harness.run("stub-fused", 2**31 + 31, 2.0, False,
+                      t_start=time.perf_counter(), accelerator=False,
+                      root=tmp_path, bench_dir=tmp_path / "bench")
+    assert out["correct"], out["checks"]
+    assert all(p.read_bytes() == b for p, b in before.items()
+               if p.name != "BENCHMARK.json")

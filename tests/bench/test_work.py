@@ -8,13 +8,17 @@ from pathlib import Path
 
 import pytest
 
-from bench import manifest, work
+from bench import manifest, peaks
 
 CONFIGS = sorted((manifest.BENCH / "configs").glob("*.json"))
 
 
 def _cfg(path: Path) -> dict:
     return json.loads(path.read_text())
+
+
+def _work(cfg: dict):
+    return manifest.load_block(cfg).work
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
@@ -28,16 +32,18 @@ def test_param_count_matches_the_program(path):
     from repro.models import Model
 
     cfg = _cfg(path)
-    mc = harness.model_config(cfg)
+    block = manifest.load_block(cfg)
+    mc = harness.model_config(cfg, block)
     leaves = sum(math.prod(s.shape)
                  for s in jax.tree.leaves(Model(mc).param_specs()))
-    assert work.param_count(cfg) == leaves
+    assert block.work.param_count(cfg) == leaves
     assert not mc.tie_embeddings
     assert mc.param_count() == leaves + cfg["vocab_size"] * cfg["d_model"]
 
 
 def test_granite_stage_sizes():
     cfg = _cfg(manifest.BENCH / "configs" / "granite-34b-10l.json")
+    work = _work(cfg)
     assert work.layer_params(cfg) - 2 * cfg["d_model"] == 530_055_168
     assert work.embed_params(cfg) == 301_989_888
     assert work.weight_bytes(cfg) == 2 * work.param_count(cfg)
@@ -46,6 +52,7 @@ def test_granite_stage_sizes():
 
 def test_prefill_and_decode_flops_from_true_lengths():
     cfg = _cfg(manifest.BENCH / "configs" / "starcoder2-3b.json")
+    work = _work(cfg)
     dense = 2 * cfg["n_layers"] * (work.layer_params(cfg) - 2 * cfg["d_model"])
     head = 2 * work.embed_params(cfg)
     per_key = 4 * cfg["n_layers"] * cfg["n_heads"] * cfg["head_dim"]
@@ -62,12 +69,12 @@ def test_prefill_and_decode_flops_from_true_lengths():
 
 
 def test_peaks_by_device_kind():
-    p = work.peaks("TPU v5 lite")
+    p = peaks.peaks("TPU v5 lite")
     assert p["flops_bf16"] == 197e12 and p["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
-        work.peaks("TPU v4")
+        peaks.peaks("TPU v4")
     with pytest.raises(KeyError):
-        work.peaks("cpu")
+        peaks.peaks("cpu")
 
 
 def test_shares_read_from_the_chip_trace_stay_under_100(chip_trace_ctx):
@@ -104,13 +111,14 @@ def chip_trace_ctx(tmp_path):
         (manifest.ROOT / "tests" / "bench" / "fixtures" /
          "granite-completion-0.5s.xplane.pb.gz").read_bytes()))
     reduced = trace_reduce.reduce_dir(tmp_path, manifest.BENCH / "layers.json")
+    # the fixture was recorded with six of the stage's layers
+    cfg = dict(_cfg(manifest.BENCH / "configs" / "granite-34b-10l.json"),
+               n_layers=6)
     return SimpleNamespace(
-        # the fixture was recorded with six of the stage's layers
-        cfg=dict(_cfg(manifest.BENCH / "configs" / "granite-34b-10l.json"),
-                 n_layers=6),
+        cfg=cfg, block=manifest.load_block(cfg),
         records={}, trace=reduced, prefilled=[814, 128],
         decode_contexts=[814 + j for j in range(1, 25)] + [129, 130],
         counters={"prefill_tokens_total": 942,
                   "prefill_padded_tokens": 4 * 1024 + 4 * 128,
                   "useful_steps": 26},
-        peaks=work.peaks("TPU v5 lite"))
+        peaks=peaks.peaks("TPU v5 lite"))

@@ -6,8 +6,8 @@ Kept out of ``conftest.py``: the repository's tests import their own
 ``tiny_root`` writes, under a temporary directory, a ``BENCHMARK.json``
 with two small cells (one fused engine, one two-replica disaggregated
 cluster) and their configuration, deployment and traffic files, in the
-layout the real benchmark uses. The metric readers and the layer table
-are copied from the real ``bench/``.
+layout the real benchmark uses. The metric readers, the block packages
+and the layer table are copied from the real ``bench/``.
 """
 
 from __future__ import annotations
@@ -55,6 +55,8 @@ def write_tiny_root(root: Path) -> Path:
     for sub in ("configs", "deployments", "traffic"):
         (bench / sub).mkdir(parents=True, exist_ok=True)
     shutil.copytree(ROOT / "bench" / "metrics", bench / "metrics")
+    shutil.copytree(ROOT / "bench" / "blocks", bench / "blocks",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(ROOT / "bench" / "layers.json", bench / "layers.json")
     (bench / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
     (bench / "traffic" / "tiny-mix.json").write_text(json.dumps(TINY_MIX))
